@@ -26,13 +26,13 @@ via ``benchmarks.common.emit_json``; ``benchmarks/run.py`` points them at
   * ``serve_arr_nocache_c{c}_m{m}``  — result cache disabled: what
     coalescing + plan caching buy on their own (the honesty row — every
     request executes).
-  * ``serve_net_c{c}_m{m}``          — the same workload through a REAL
-    second OS process over TCP (``PGServer``/``PGClient``), c client
-    connections; measures the wire + framing overhead on top of the
+  * ``serve_net_c{c}_m{m}``          — the same workload over TCP
+    (``PGServer`` on a thread of this process, c ``PGClient``
+    connections); measures the wire + framing overhead on top of the
     in-process rows.
 
 Both paths are warmed first (jit compiles for every pattern shape and
-every Q bucket; the net server warms itself before LISTENING), so rows
+every Q bucket; the wire server serves the same warmed graph), so rows
 measure steady-state serving, not compilation; every row is
 best-of-``repeats`` replays (closed-loop threading is highly exposed to
 cgroup CPU-quota throttling — the best run is the least-interfered
@@ -66,11 +66,10 @@ def run(m: int = 50_000, requests: int = 64, concurrencies=(1, 2, 4, 8),
         run_sequential,
         run_workload,
         run_workload_net,
-        spawn_server,
         synthetic_workload,
         warm_serving_path,
     )
-    from repro.service import PGClient, Service, ServiceConfig
+    from repro.service import PGClient, PGServer, Service, ServiceConfig
 
     pg = build_tenant_graph("arr", m, seed=seed)
     graphs = {"tenant0": pg}
@@ -184,28 +183,27 @@ def run(m: int = 50_000, requests: int = 64, concurrencies=(1, 2, 4, 8),
 
     if not net:
         return
-    # -- cross-process: same workload through a spawned server over TCP
-    proc, port = spawn_server(["--graphs", "1", "--backend", "arr",
-                               "--m", str(m), "--seed", str(seed), "--warm"])
-    try:
-        with PGClient(port=port) as c0:
-            _verify_service(lambda p: c0.query("tenant0", p), pg, pool)
-        for c in (1, max(concurrencies)):
-            met = run_workload_net(port, wl, c, repeats=repeats)
-            emit_json(
-                f"serve_net_c{c}_m{m}", met["wall_s"] / requests,
-                path=json_path, qps=round(met["qps"], 1), concurrency=c,
-                requests=requests, m=m, p50_ms=round(met["p50_ms"], 3),
-                p95_ms=round(met["p95_ms"], 3),
-                speedup=round(met["qps"] / seq["qps"], 2), runs=repeats,
-                mode="service-net",
-            )
-        with PGClient(port=port) as c0:
-            c0.shutdown()
-        proc.wait(timeout=120)
-    finally:
-        if proc.poll() is None:
-            proc.kill()
+    # -- wire: same workload through a PGServer over TCP.  The server runs
+    # as a thread of THIS process, which already holds the device: a
+    # spawned server process could not open an accelerator the parent holds.
+    with Service() as svc:
+        svc.add_graph("tenant0", pg)
+        server = PGServer(svc, port=0).start()
+        try:
+            with PGClient(port=server.port) as c0:
+                _verify_service(lambda p: c0.query("tenant0", p), pg, pool)
+            for c in (1, max(concurrencies)):
+                met = run_workload_net(server.port, wl, c, repeats=repeats)
+                emit_json(
+                    f"serve_net_c{c}_m{m}", met["wall_s"] / requests,
+                    path=json_path, qps=round(met["qps"], 1), concurrency=c,
+                    requests=requests, m=m, p50_ms=round(met["p50_ms"], 3),
+                    p95_ms=round(met["p95_ms"], 3),
+                    speedup=round(met["qps"] / seq["qps"], 2), runs=repeats,
+                    mode="service-net",
+                )
+        finally:
+            server.close()
 
 
 if __name__ == "__main__":
@@ -213,7 +211,7 @@ if __name__ == "__main__":
     ap.add_argument("--m", type=int, default=50_000)
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--no-net", action="store_true",
-                    help="skip the cross-process TCP rows")
+                    help="skip the TCP wire rows")
     ap.add_argument("--json-path", default=None)
     a = ap.parse_args()
     run(m=a.m, requests=a.requests, net=not a.no_net, json_path=a.json_path)

@@ -52,6 +52,9 @@ import sys
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     small = "--small" in sys.argv
     print("name,us_per_call,derived")
 

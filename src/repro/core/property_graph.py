@@ -1272,7 +1272,7 @@ class PropGraph:
         return blocks
 
     def sample(self, seeds_or_pattern, fanouts, *, key=None, seed: int = 0,
-               pattern=None, use_pallas: bool = False):
+               pattern=None):
         """Fused property-filtered neighborhood sampling — the one-launch
         pattern→sample path (docs/ARCHITECTURE.md §15).
 
@@ -1286,8 +1286,7 @@ class PropGraph:
         may be sampled at EVERY layer (relationship, predicates, endpoint
         labels); overlay tombstones are always excluded.  ``key``/``seed``:
         the base PRNG key — results are bitwise-reproducible given it
-        (layer l draws from ``fold_in(key, l)`` only).  ``use_pallas``
-        opts the TPU window kernel in for layer 0.
+        (layer l draws from ``fold_in(key, l)`` only).
 
         Returns ``SampledBlock``s innermost-first (``blocks[-1].dst_nodes``
         = the seed batch); node ids are INTERNAL [0, n) ids — index device
@@ -1333,8 +1332,7 @@ class PropGraph:
                 ids = ids[~self._dead_v[ids]]
             nb, _ei, mk = neighbor_sample(
                 seg, dstv, g.n, g.m, ids, k0, fanout=fanouts[0],
-                edge_words=ew_words, max_deg=max_deg,
-                use_pallas=use_pallas)
+                edge_words=ew_words, max_deg=max_deg)
             frontier = ids.astype(np.int32)
             nbrs0 = np.asarray(nb)[:len(ids)]
             mask0 = np.asarray(mk)[:len(ids)]
@@ -1467,7 +1465,7 @@ class PropGraph:
         av = self._alive_vertex_mask()
         if av is not None:
             v_ok = av if v_ok is None else v_ok & av
-        return g, v_ok, e_ok, direction
+        return g, v_ok, e_ok
 
     def pagerank(
         self,
@@ -1487,19 +1485,22 @@ class PropGraph:
         no filter this is the classic §I kernel (``repro.graph.pagerank``
         delegates here).  Under a mesh the per-step aggregation
         all-reduces partial sums with ``psum`` — equal to the
-        single-device ranks within float tolerance."""
+        single-device ranks within float tolerance.
+
+        Rank always flows along each edge's stored direction: the
+        pattern only selects edges, so ``(b)<-[:r]-(a)`` selects the same
+        a→b edges as ``(a)-[:r]->(b)`` and ranks them alike."""
         from repro import traverse
 
         _obs_traverse("pagerank", int(iters), None)
-        g, v_ok, e_ok, direction = self._subgraph_filters(pattern)
+        g, v_ok, e_ok = self._subgraph_filters(pattern)
         w, e_ok = self._weighted_edge_filter(e_ok, weight)
         if self.mesh is not None:
             return traverse.pagerank_sharded(
                 g, v_ok, e_ok, w, mesh=self.mesh, damping=damping,
-                iters=iters, direction=direction)
+                iters=iters)
         return traverse.pagerank_masked(
-            g, v_ok, e_ok, w, damping=damping, iters=iters,
-            direction=direction)
+            g, v_ok, e_ok, w, damping=damping, iters=iters)
 
     def communities(self, pattern=None, *, max_iters: int = 64) -> jax.Array:
         """Community labels by synchronous label propagation on the
@@ -1516,7 +1517,7 @@ class PropGraph:
         from repro import traverse
 
         _obs_traverse("communities", int(max_iters), None)
-        g, v_ok, e_ok, _ = self._subgraph_filters(pattern)
+        g, v_ok, e_ok = self._subgraph_filters(pattern)
         return traverse.label_propagation_masked(
             g, v_ok, e_ok, max_iters=max_iters)
 

@@ -16,11 +16,12 @@ The PACKED form works on the (K, W = ceil(N/32)) uint32 word plane instead.
 There is no MXU trick for bitwise OR, but none is needed: the scan is
 bandwidth-bound, and the packed plane moves 8× fewer bytes than int8 (32×
 fewer than the f32 the MXU path casts to).  The kernel is a VPU loop over K
-accumulating ``acc |= select[a] & plane[a]`` on (Q, Wt) uint32 lanes —
-query masks arrive pre-broadcast as full-word 0x00000000/0xFFFFFFFF selects
-so the inner loop is two vector ops per row.  uint32 is a 32-bit lane type
-⇒ (8, 128) minimum tile; Wt = 512 words (= 16 384 entities) keeps the
-(K, Wt) block at K=512 to 1 MiB VMEM.
+accumulating ``acc |= select[:, a] & plane[a]`` on (Q, Wt) uint32 lanes.
+The query masks arrive as one (Q, K) f32 0/1 block; each step widens
+column ``a`` to a (Q, 1) full-word 0x00000000/0xFFFFFFFF select in
+registers.  uint32 is a 32-bit lane type ⇒ (8, 128) minimum tile;
+Wt = 512 words (= 16 384 entities) keeps the (K, Wt) block at K=512 to
+1 MiB VMEM.
 """
 from __future__ import annotations
 
@@ -104,14 +105,18 @@ def bitmap_query_batched_pallas(bitmap: jax.Array, attr_masks: jax.Array, *,
 
 
 def _bitmap_query_packed_kernel(select_ref, plane_ref, out_ref):
-    select = select_ref[...]      # (Q, K) uint32 — 0 or 0xFFFFFFFF per query row
-    k = select.shape[1]
+    select = select_ref[...]      # (Q, K) f32 — 1.0 where query row selects a
+    q, k = select.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (q, k), 1)
 
     def body(a, acc):
-        return acc | (select[:, a][:, None] & plane_ref[a, :][None, :])
+        # Mosaic cannot slice a value at a traced lane index, so column a
+        # of the selects is picked by a lane-masked max instead of select[:, a]
+        pick = jnp.max(jnp.where(lane == a, select, 0.0), axis=1, keepdims=True)
+        col = jnp.where(pick > 0.5, jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
+        return acc | (col & plane_ref[pl.ds(a, 1), :])   # (Q, 1) & (1, Wt)
 
-    acc0 = jnp.zeros_like(out_ref)
-    out_ref[...] = jax.lax.fori_loop(0, k, body, acc0)
+    out_ref[...] = jax.lax.fori_loop(0, k, body, jnp.zeros(out_ref.shape, jnp.uint32))
 
 
 @functools.partial(jax.jit, static_argnames=("tile_w", "interpret"))
@@ -124,29 +129,25 @@ def bitmap_query_batched_packed_pallas(
 
     The fori_loop over K keeps VMEM at (K, Wt) + (Q, Wt) — no (Q, K, Wt)
     intermediate — while each (K, Wt) plane tile streams from HBM exactly
-    once for all Q query rows.
+    once for all Q query rows.  W need not divide by the tile: each output
+    word reads only its own plane column, so the ragged last block's
+    out-of-range lanes never reach a kept word, and the plane is not
+    re-padded (copied) per call.
     """
     k, w = plane.shape
     q = attr_masks.shape[0]
     tile_w = min(tile_w, w)
-    pad = (-w) % tile_w
-    if pad:
-        plane = jnp.pad(plane, ((0, 0), (0, pad)))
-    w_pad = w + pad
-    select = jnp.where(attr_masks, jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
-
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _bitmap_query_packed_kernel,
-        grid=(w_pad // tile_w,),
+        grid=(pl.cdiv(w, tile_w),),
         in_specs=[
             pl.BlockSpec((q, k), lambda i: (0, 0)),        # selects: replicated
             pl.BlockSpec((k, tile_w), lambda i: (0, i)),   # plane: word tiles
         ],
         out_specs=pl.BlockSpec((q, tile_w), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((q, w_pad), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((q, w), jnp.uint32),
         interpret=interpret,
-    )(select, plane)
-    return out[:, :w]
+    )(attr_masks.astype(jnp.float32), plane)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_w", "interpret"))

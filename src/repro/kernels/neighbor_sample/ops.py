@@ -19,14 +19,11 @@ count across QPS traffic is therefore bounded by the bucket grids, which
 :func:`sample_compile_count` (backed by the ``pg_sample_compiles``
 process counter) makes assertable.
 
-Lowerings: the selection math is plain XLA (`lax.top_k` over negated
-priorities — ties break to the lower lane); on TPU the single-request
-window gather+select can run the Pallas kernel
-(``kernel.window_select_pallas``), which tests pin bitwise against the
-XLA lowering in interpret mode.  The batched/vmapped entries always use
-the XLA lowering (one fused program; composes with GSPMD-sharded
-``seg``/``dst`` under a mesh, where sampling stays owner-device local —
-each seed's window gather touches only the shard holding its slice).
+Lowering: the selection math is plain XLA (`lax.top_k` over negated
+priorities — ties break to the lower lane) on every entry — one fused
+program that composes with GSPMD-sharded ``seg``/``dst`` under a mesh,
+where sampling stays owner-device local (each seed's window gather
+touches only the shard holding its slice).
 
 Randomness contract: callers pass explicit PRNG keys; every program
 derives its uniforms ONLY from the per-request key (row r of a batched
@@ -153,18 +150,10 @@ def _window_select(seg, dst, m: int, n: int, seeds, valid, ew_words, u,
     return nbrs, eids, ok
 
 
-@partial(jax.jit, static_argnames=("m", "n", "fanout", "window", "use_pallas"))
+@partial(jax.jit, static_argnames=("m", "n", "fanout", "window"))
 def _sample_one(seg, dst, seeds, valid, ew_words, key, *, m: int, n: int,
-                fanout: int, window: int, use_pallas: bool = False):
+                fanout: int, window: int):
     u = jax.random.uniform(key, (seeds.shape[0], window))
-    if use_pallas:
-        from repro.kernels.neighbor_sample.kernel import window_select_pallas
-
-        sidx = jnp.clip(seeds, 0, max(n - 1, 0))
-        start = seg[sidx]
-        deg = jnp.where(valid, seg[sidx + 1] - start, 0)
-        return window_select_pallas(
-            start, deg, dst, ew_words, u, m=m, fanout=fanout)
     return _window_select(seg, dst, m, n, seeds, valid, ew_words, u, fanout)
 
 
@@ -236,26 +225,22 @@ def _window_for(max_deg: Optional[int], seg, fanout: int) -> int:
 
 
 def neighbor_sample(seg, dst, n: int, m: int, seeds, key, *, fanout: int,
-                    edge_words=None, max_deg: Optional[int] = None,
-                    use_pallas: bool = False):
+                    edge_words=None, max_deg: Optional[int] = None):
     """Sample ≤ ``fanout`` filtered out-neighbors per seed, one launch.
 
     ``edge_words``: packed (ceil(m/32),) uint32 edge-allowed bitmap (None
     = every edge).  Returns (nbrs, eids, mask) shaped (S_cap, fanout) with
     S_cap = ``bucketed_seeds(len(seeds))``; rows past the real seed count
-    are fully masked.  ``use_pallas`` opts the TPU window kernel in (off
-    by default; the XLA lowering is the canonical path and the two are
-    pinned bitwise)."""
+    are fully masked."""
     cap = bucketed_seeds(np.asarray(seeds).size)
     window = _window_for(max_deg, seg, fanout)
     sd, valid = _pad_seeds(seeds, cap)
     _note_launch("one", (cap, window, int(fanout), edge_words is not None,
-                         bool(use_pallas), n, m))
+                         n, m))
     return _sample_one(
         seg, dst, sd, valid,
         None if edge_words is None else jnp.asarray(edge_words),
-        key, m=m, n=n, fanout=int(fanout), window=window,
-        use_pallas=bool(use_pallas))
+        key, m=m, n=n, fanout=int(fanout), window=window)
 
 
 def neighbor_sample_batched(seg, dst, n: int, m: int, seeds, valid, keys, *,

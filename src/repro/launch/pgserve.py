@@ -42,6 +42,7 @@ import numpy as np
 
 __all__ = [
     "build_tenant_graph",
+    "tenant_draws",
     "pattern_pool",
     "synthetic_workload",
     "run_workload",
@@ -66,20 +67,30 @@ def build_tenant_graph(backend: str, m: int, *, mesh=None, seed: int = 0):
     from repro.core import PropGraph
     from repro.graph import random_uniform_graph
 
-    rng = np.random.default_rng(seed)
     src, dst = random_uniform_graph(m, seed=seed)
     pg = PropGraph(backend=backend, mesh=mesh).add_edges_from(src, dst)
     nodes = np.asarray(pg.graph.node_map)
-    pg.add_node_labels(nodes, rng.choice([f"l{i}" for i in range(N_LABELS)],
-                                         size=len(nodes)))
     es, ed = np.asarray(pg.graph.src), np.asarray(pg.graph.dst)
-    pg.add_edge_relationships(nodes[es], nodes[ed],
-                              rng.choice(RELS, size=len(es)))
-    pg.add_node_properties("age", nodes,
-                           rng.integers(0, 90, len(nodes)).astype(np.int32))
-    pg.add_edge_properties("w", nodes[es], nodes[ed],
-                           rng.uniform(0.5, 2.0, len(es)).astype(np.float32))
+    labels, rels, age, w = tenant_draws(len(nodes), len(es), seed=seed)
+    pg.add_node_labels(nodes, labels)
+    pg.add_edge_relationships(nodes[es], nodes[ed], rels)
+    pg.add_node_properties("age", nodes, age)
+    pg.add_edge_properties("w", nodes[es], nodes[ed], w)
     return pg
+
+
+def tenant_draws(n: int, m: int, *, seed: int = 0):
+    """The seeded attribute draws ``build_tenant_graph`` attaches, as host
+    arrays ``(labels, rels, age, w)``: a label and an ``age`` per vertex in
+    ``node_map`` order, a relationship and a ``w`` weight per edge in DI
+    (sorted ``(src, dst)``) order — what a numpy reference rebuilds the
+    tenant from."""
+    rng = np.random.default_rng(seed)
+    labels = rng.choice([f"l{i}" for i in range(N_LABELS)], size=n)
+    rels = rng.choice(RELS, size=m)
+    age = rng.integers(0, 90, n).astype(np.int32)
+    w = rng.uniform(0.5, 2.0, m).astype(np.float32)
+    return labels, rels, age, w
 
 
 def pattern_pool() -> List[str]:
@@ -418,7 +429,9 @@ def net_smoke(m: int = 600, seed: int = 0, tmp_dir: Optional[str] = None) -> Non
     """CI gate for the network path: one server SUBPROCESS serving all
     three backends; a client in THIS process verifies every pool pattern
     bitwise against an in-process ``PropGraph.match`` reference (the
-    tenant build is seeded, so both processes construct identical graphs),
+    tenant build is seeded, so both processes construct identical graphs;
+    ``main`` keeps this process on the CPU so the server child can own the
+    accelerator),
     then exercises pipelining, the semiring analytics verbs (weighted
     shortest paths / PageRank / communities), a variable-length traversal
     query (plus the plan-time string-predicate rejection), wire mutation +
@@ -907,6 +920,17 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+    if args.net:
+        # the spawned server owns the accelerator; a parent that touched it
+        # first would hold it and the child could not start.  Pin this
+        # process (client and in-process references) to the CPU before any
+        # JAX backend starts.
+        import jax
+
+        jax.config.update("jax_platforms", "cpu")
     if args.serve:
         serve(port=args.port, host=args.host, backend=args.backend,
               backends=args.backends.split(",") if args.backends else None,

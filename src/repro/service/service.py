@@ -615,9 +615,10 @@ class Service:
 
         Failure isolation: planning errors (bad property names etc.) fail
         only their own request; if the COALESCED execution raises, the
-        group re-runs per-request so one poisoned plan cannot take down
-        co-batched tenants.  Consistency under concurrent mutators: the
-        version is read before executing and re-checked after — a
+        group re-runs per-request (counted in ``group_fallbacks``) so one
+        poisoned plan cannot take down co-batched tenants.  Consistency
+        under concurrent mutators: the version is read before executing
+        and re-checked after — a
         mid-flight mutation (torn graph/store view) retries the group and
         nothing torn is ever cached or returned as authoritative.
 
@@ -667,7 +668,9 @@ class Service:
             except Exception as e:  # noqa: BLE001
                 if pg.version != version and attempt < 2:
                     continue  # a concurrent mutation tore the view — retry
-                # the group itself failed: isolate by per-request execution
+                # the group itself failed: isolate by per-request execution,
+                # counted so a device fault behind it cannot pass unseen
+                self._bump("group_fallbacks")
                 results = []
                 for c in keys:
                     try:

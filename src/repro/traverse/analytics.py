@@ -45,7 +45,6 @@ from repro.traverse.engine import (
     MINLABEL,
     TROPICAL,
     _all_edges,
-    _ends,
     _pad_edges,
     _sharded_relax_fn,
     semiring_relax,
@@ -200,7 +199,7 @@ def shortest_paths_sharded(
 
 
 # ------------------------------------------------------------ pagerank (+, ×)
-@partial(jax.jit, static_argnames=("iters", "direction"))
+@partial(jax.jit, static_argnames=("iters",))
 def pagerank_masked(
     g: DIGraph,
     vertex_allowed: Optional[jax.Array] = None,
@@ -209,7 +208,6 @@ def pagerank_masked(
     *,
     damping: float = 0.85,
     iters: int = 20,
-    direction: int = 1,
 ) -> jax.Array:
     """PageRank on the property-filtered subgraph: (n,) f32 ranks, 0.0
     outside ``vertex_allowed``.
@@ -227,7 +225,7 @@ def pagerank_masked(
          else weights.astype(jnp.float32))
     if edge_allowed is not None:
         w = jnp.where(edge_allowed, w, jnp.float32(0))
-    tail, head = _ends(g, direction)
+    tail, head = g.src, g.dst
     if vertex_allowed is not None:
         w = jnp.where(vertex_allowed[tail] & vertex_allowed[head], w,
                       jnp.float32(0))
@@ -242,7 +240,7 @@ def pagerank_masked(
     inv_deg = jnp.where(out_deg > 0, 1.0 / jnp.maximum(out_deg, 1e-30), 0.0)
 
     def step(r, _):
-        agg = semiring_relax(g, r * inv_deg, w, COUNTING, direction=direction)
+        agg = semiring_relax(g, r * inv_deg, w, COUNTING)
         dangling = jnp.sum(jnp.where(out_deg > 0, 0.0, r))
         r_new = (1 - damping) / n_eff + damping * (agg + dangling / n_eff)
         if vertex_allowed is not None:
@@ -254,7 +252,7 @@ def pagerank_masked(
 
 
 @lru_cache(maxsize=None)
-def _sharded_pagerank_fn(mesh, direction: int):
+def _sharded_pagerank_fn(mesh):
     """Jitted power iteration whose aggregation runs under ``shard_map``:
     per-device partial contribution sums, ⊕-combined with ONE ``psum``
     all-reduce per step.  float sums reassociate across device blocks, so
@@ -262,12 +260,12 @@ def _sharded_pagerank_fn(mesh, direction: int):
     (atol), not bitwise — the one non-idempotent ⊕ in the table (§12)."""
     from repro.launch.sharding import pg_entity_shards
 
-    step_relax = _sharded_relax_fn(mesh, direction, False, COUNTING)
+    step_relax = _sharded_relax_fn(mesh, 1, False, COUNTING)
     p = pg_entity_shards(mesh)
 
     @partial(jax.jit, static_argnames=("iters",))
     def fn(g: DIGraph, v_ok, w, damping, *, iters: int):
-        tail, head, wp = _pad_edges(g, w, p, direction, COUNTING.zero)
+        tail, head, wp = _pad_edges(g, w, p, 1, COUNTING.zero)
         n_eff = jnp.maximum(jnp.sum(v_ok.astype(jnp.float32)), 1.0)
         out_deg = jax.ops.segment_sum(w, tail[: g.m], g.n)
         inv_deg = jnp.where(out_deg > 0, 1.0 / jnp.maximum(out_deg, 1e-30), 0.0)
@@ -294,7 +292,6 @@ def pagerank_sharded(
     mesh,
     damping: float = 0.85,
     iters: int = 20,
-    direction: int = 1,
 ) -> jax.Array:
     """``pagerank_masked`` with the per-step shard_map/``psum`` all-reduce
     layout; equal to the single-device path within float tolerance."""
@@ -302,12 +299,12 @@ def pagerank_sharded(
          else weights.astype(jnp.float32))
     if edge_allowed is not None:
         w = jnp.where(edge_allowed, w, jnp.float32(0))
-    tail, head = _ends(g, direction)
+    tail, head = g.src, g.dst
     v_ok = (jnp.ones((g.n,), jnp.bool_) if vertex_allowed is None
             else vertex_allowed)
     if vertex_allowed is not None:
         w = jnp.where(v_ok[tail] & v_ok[head], w, jnp.float32(0))
-    fn = _sharded_pagerank_fn(mesh, direction)
+    fn = _sharded_pagerank_fn(mesh)
     return fn(g, v_ok, w, jnp.float32(damping), iters=iters)
 
 

@@ -43,6 +43,26 @@ def test_bitmap_query_batched(q, k, n):
         assert bool(jnp.all(out[i] == bitmap_query(bm, masks[i])))
 
 
+@pytest.mark.parametrize("w", [5, 100, 513, 1500])  # below / not a multiple of the 512-word tile
+@pytest.mark.parametrize("k", [1, 7, 50])
+@pytest.mark.parametrize("q", [1, 3, 8, 64])
+def test_bitmap_query_packed_parity(q, k, w):
+    """Packed word kernels (batched and single) ≡ the XLA word reduction
+    ``dip_arr.query_any_batched_words``, bit for bit."""
+    from repro.core import dip_arr
+    from repro.kernels.bitmap_query.ops import bitmap_query_batched_packed, bitmap_query_packed
+
+    rng = np.random.default_rng(q * 10_000 + k * 100 + w)
+    plane = jnp.asarray(rng.integers(0, 2**32, (k, w), dtype=np.uint32))
+    masks = jnp.asarray(rng.random((q, k)) < 0.3)
+    store = dip_arr.DIPArr(bitmap=plane, k=k, n=32 * w, packed=True)
+    ref = np.asarray(dip_arr.query_any_batched_words(store, masks))
+    got = np.asarray(bitmap_query_batched_packed(plane, masks))
+    assert got.dtype == np.uint32 and np.array_equal(got, ref)
+    one = np.asarray(bitmap_query_packed(plane, masks[q - 1]))
+    assert np.array_equal(one, ref[q - 1])
+
+
 # -------------------------------------------------------------------- seg_mm
 @pytest.mark.parametrize("n,e,d", [(64, 256, 16), (500, 2000, 64), (37, 91, 8),
                                    (1000, 5000, 128)])

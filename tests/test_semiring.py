@@ -23,7 +23,7 @@ The contracts under test:
   inserts and weight updates into the parent; forks keep weight writes
   private; the service's analytics result cache dies on a weight-property
   ``MutationEvent`` and survives unrelated property writes.
-* hypothesis (optional dep) property tests: relax axioms over random
+* hypothesis property tests: relax axioms over random
   graphs, seed-permutation invariance, pattern-reorientation invariance.
 """
 import os
@@ -49,26 +49,11 @@ from repro.traverse import (
     semiring_relax,
 )
 
-try:
-    from hypothesis import given, strategies as st
-
-    HAVE_HYP = True
-except ImportError:
-    HAVE_HYP = False
+from hypothesis import given, strategies as st
 
 
-def _hyp_seeded(f):
-    """@given(seed=...) when hypothesis is installed, a skip stub when not
-    (requirements-dev.txt makes it optional; conftest pins the profile)."""
-    if not HAVE_HYP:
-        @pytest.mark.skip(reason="hypothesis not installed (requirements-dev.txt)")
-        def stub():
-            pass
-
-        stub.__name__ = f.__name__
-        stub.__doc__ = f.__doc__
-        return stub
-    return given(seed=st.integers(min_value=0, max_value=30))(f)
+# property tests draw their graph seed here; conftest pins the profile
+_hyp_seeded = given(seed=st.integers(min_value=0, max_value=30))
 
 
 BACKENDS = ("arr", "list", "listd")
@@ -411,9 +396,9 @@ def test_communities_two_cycle_oscillates_to_the_cap():
     assert np.asarray(pg.communities(max_iters=7)).tolist() == [1, 0]
 
 
-# -------------------------------------------------- hypothesis (optional)
+# ------------------------------------------------------------ hypothesis
 @_hyp_seeded
-def test_relax_absorption_randomized(seed=0):
+def test_relax_absorption_randomized(seed):
     """Zero-vector absorption holds on arbitrary random graphs."""
     rng = np.random.default_rng(seed)
     n, m = int(rng.integers(2, 30)), int(rng.integers(1, 80))
@@ -430,7 +415,7 @@ def test_relax_absorption_randomized(seed=0):
 
 
 @_hyp_seeded
-def test_shortest_paths_seed_permutation_invariance(seed=0):
+def test_shortest_paths_seed_permutation_invariance(seed):
     """Distances are a function of the seed SET: order and duplicates in
     the seed list cannot change the answer (bitwise)."""
     pg = _build("arr", n=20, m=60, seed=seed)
@@ -443,7 +428,7 @@ def test_shortest_paths_seed_permutation_invariance(seed=0):
 
 
 @_hyp_seeded
-def test_pattern_reorientation_invariance(seed=0):
+def test_pattern_reorientation_invariance(seed):
     """``(a:x)-[:r]->(b:y)`` and ``(b:y)<-[:r]-(a:x)`` denote the same
     edge set; under an undirected traversal (and for communities, which
     are undirected by construction) the answers are bitwise-identical."""

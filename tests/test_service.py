@@ -214,6 +214,25 @@ def test_bad_request_does_not_poison_cobatched_group():
         _assert_same_result(out[good_c], pg.match(PATTERNS[0]))
 
 
+def test_failed_coalesced_group_is_counted_and_rerun_per_request():
+    """When the coalesced launch itself raises, every request of the group
+    re-runs on its own, and ``group_fallbacks`` counts the group."""
+    pg = _build("arr")
+    with Service() as svc:
+        svc.add_graph("g", pg)
+        asts = dict(svc._canon(p) for p in PATTERNS[:2])
+
+        def broken(*_a, **_k):
+            raise RuntimeError("coalesced launch failed")
+
+        svc._execute_plans = broken
+        out = svc._serve_group(pg, "g", None, asts)
+        stats = svc.stats()
+    assert stats["group_fallbacks"] == 1 and stats.get("errors", 0) == 0
+    for (c, _), p in zip(asts.items(), PATTERNS[:2]):
+        _assert_same_result(out[c], pg.match(p))
+
+
 def test_result_cache_hit_and_fastpath_accounting():
     pg = _build("arr")
     with Service() as svc:

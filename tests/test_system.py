@@ -77,3 +77,29 @@ def test_bfs_on_typed_subgraph():
     assert d[1] == 1 and d[2] == 2 and d[3] == -1 or d[3] > 0  # 3 unreachable via 'a' from 0->1->2 (edge 2->3 is 'b')
     # precise: path 0-a->1-a->2 (b blocks 2->3); 0-b->3 blocked
     assert d.tolist()[:5] == [0, 1, 2, -1, -1]
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_placement(monkeypatch, env_dir):
+    """Entry points keep JAX's compile cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says and set nothing themselves; without
+    it, at the fixed ``<checkout>/.jax_cache``."""
+    import jax
+
+    from repro.launch.compile_cache import CHECKOUT, use_compile_cache
+
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        use_compile_cache()
+        got = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    if env_dir is None:
+        assert got == str(CHECKOUT / ".jax_cache")
+        assert (CHECKOUT / "src" / "repro").is_dir()
+    else:
+        assert got == prev
