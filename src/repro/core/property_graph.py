@@ -48,21 +48,16 @@ from repro.obs.metrics import enabled as _obs_enabled
 from repro.overlay.delta import AttrDelta, EdgeDelta, MutationEvent, pair_keys
 
 
-def _obs_traverse(op: str, rounds: Optional[int], seeds: Optional[int]) -> None:
+def _obs_traverse(op: str, seeds: Optional[int]) -> None:
     """Frontier/semiring engine accounting (docs/ARCHITECTURE.md §13):
-    per-op run counts plus the host-known shape of the work — relax-round
-    budgets and seed-set sizes.  The exact converged round count lives
-    inside a jitted ``while_loop``; reading it back would force a device
-    sync per call, so the budget (``k``/``max_iters``, the loop's bound)
-    is what's recorded.  Host-side only, never a device sync."""
+    per-op run counts plus the seed-set size, the host-known shape of the
+    work.  The rounds that ran live inside a jitted ``while_loop``, and
+    reading them back would force a device sync per call, so none are
+    recorded.  Host-side only, never a device sync."""
     if not _obs_enabled():
         return
     _OBS.counter("pg_traverse_runs", "frontier/semiring engine runs",
                  op=op).inc()
-    if rounds is not None:
-        _OBS.histogram("pg_traverse_relax_rounds",
-                       "relax-round budget per run (loop bound)",
-                       buckets=_SIZE_BUCKETS, op=op).observe(rounds)
     if seeds is not None:
         _OBS.histogram("pg_traverse_seed_size",
                        "seed/frontier-origin set size per run",
@@ -1139,7 +1134,7 @@ class PropGraph:
         g = self._require_graph()
         if impl not in (None, "frontier", "csr"):
             raise ValueError(f"unknown impl {impl!r}")
-        _obs_traverse("khop", int(k), int(np.asarray(seeds).size))
+        _obs_traverse("khop", int(np.asarray(seeds).size))
         v_tail, v_head, e_mask, direction = traverse.single_hop_filters(
             self, pattern)
         e_ok = jnp.ones((g.m,), jnp.bool_) if e_mask is None else e_mask
@@ -1355,7 +1350,7 @@ class PropGraph:
         from repro import traverse
 
         g = self._require_graph()
-        _obs_traverse("components", int(max_iters), None)
+        _obs_traverse("components", None)
         v_tail, v_head, e_mask, direction = traverse.single_hop_filters(
             self, pattern)
         tail, head = (g.src, g.dst) if direction == 1 else (g.dst, g.src)
@@ -1412,9 +1407,7 @@ class PropGraph:
         from repro import traverse
 
         g = self._require_graph()
-        _obs_traverse("shortest_paths",
-                      None if max_iters is None else int(max_iters),
-                      int(np.asarray(seeds).size))
+        _obs_traverse("shortest_paths", int(np.asarray(seeds).size))
         v_tail, v_head, e_mask, direction = traverse.single_hop_filters(
             self, pattern)
         e_ok = jnp.ones((g.m,), jnp.bool_) if e_mask is None else e_mask
@@ -1492,7 +1485,7 @@ class PropGraph:
         a→b edges as ``(a)-[:r]->(b)`` and ranks them alike."""
         from repro import traverse
 
-        _obs_traverse("pagerank", int(iters), None)
+        _obs_traverse("pagerank", None)
         g, v_ok, e_ok = self._subgraph_filters(pattern)
         w, e_ok = self._weighted_edge_filter(e_ok, weight)
         if self.mesh is not None:
@@ -1516,7 +1509,7 @@ class PropGraph:
         program over the placed arrays)."""
         from repro import traverse
 
-        _obs_traverse("communities", int(max_iters), None)
+        _obs_traverse("communities", None)
         g, v_ok, e_ok = self._subgraph_filters(pattern)
         return traverse.label_propagation_masked(
             g, v_ok, e_ok, max_iters=max_iters)

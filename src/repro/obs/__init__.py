@@ -6,9 +6,10 @@ One instrumentation vocabulary for the whole stack:
   histograms in per-``Service`` and process-``GLOBAL`` registries, with
   Prometheus text exposition and a module-level kill switch
   (``set_enabled(False)`` → every call site degrades to one branch).
-* ``obs.trace`` — per-query span trees (parse→plan→cache→batch→execute→
-  serialize) with wire-propagated trace ids, a bounded trace ring and a
-  slow-query log.
+* ``obs.trace`` — per-query span trees (parse→batch.wait→cache→plan→
+  execute→device.wait→serialize) with wire-propagated trace ids, a
+  bounded trace ring and a slow-query log; each stage is also a ``pg.``
+  host event in a ``jax.profiler`` trace.
 * ``obs.profile`` — EXPLAIN ANALYZE: executed plans annotated with
   per-stage wall times and the measured JAX compile-vs-execute split.
 """

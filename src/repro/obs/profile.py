@@ -28,8 +28,6 @@ from typing import Any, Dict, Optional
 
 import jax
 
-from repro.obs import metrics as _metrics
-
 __all__ = ["ProfileReport", "profile_match"]
 
 _now = time.perf_counter
@@ -157,6 +155,4 @@ def profile_match(pg, pattern, *, impl: Optional[str] = None):
                "fused_slots": len(plan.fused_node_slots),
                "traversal": plan.has_traversal},
     )
-    _metrics.GLOBAL.counter(
-        "pg_profile_runs", "explain_analyze invocations").inc()
     return result, report

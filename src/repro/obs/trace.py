@@ -1,8 +1,8 @@
 """Per-query trace spans (docs/ARCHITECTURE.md §13).
 
-A ``Trace`` is one query's tree of timed ``Span``s — the canonical span
-vocabulary is parse → plan → cache → batch.wait → compile → execute →
-serialize, though callers may nest anything.  Traces are explicit
+A ``Trace`` is one query's tree of timed ``Span``s — the served path's
+span vocabulary is parse → batch.wait → cache → plan → execute →
+device.wait → serialize, though callers may nest anything.  Traces are explicit
 objects handed along the call chain rather than thread-locals, because a
 served query hops threads twice (submit thread → scheduler worker →
 session writer) and implicit context would silently detach.
@@ -13,23 +13,60 @@ back in the response header) or minted locally.  Finished traces land in
 a per-service ``TraceBuffer``: a bounded ring plus a slow-query ring for
 traces over a wall-time threshold.
 
-Everything here is wall-clock bookkeeping on the host — ``Span`` never
-touches device state, so a span around a jitted call measures dispatch
-unless the caller blocks (the EXPLAIN ANALYZE path in obs/profile.py is
-the one that inserts ``block_until_ready`` to split compile from
-execute).
+Every span is wall-clock time on the host — ``Span`` never touches
+device state, so a span around a jitted call measures dispatch unless the
+caller blocks (the served reply's ``device.wait`` span is that block, and
+EXPLAIN ANALYZE in obs/profile.py inserts ``block_until_ready`` to split
+compile from execute).
+
+A span used as a context manager also writes a profiler host event named
+``"pg." + name`` (``jax.profiler.TraceAnnotation``) over the same
+interval, so a device trace taken with ``jax.profiler`` shows the
+program's stages on its own clock and each idle gap can be charged to
+one.  Event names carry no per-request data.  A ``Span`` made with no
+trace (``stage(name)``) times a stage once — a coalesced group's, or one
+no request trace exists for yet — whose endpoints ``Trace.add_span``
+then copies into each member's tree.  With the profiler off an event
+costs under a microsecond; without JAX it costs nothing.
 """
 from __future__ import annotations
 
+import functools
 import threading
 import time
 import uuid
 from collections import deque
 from typing import Any, Dict, List, Optional
 
-__all__ = ["Span", "Trace", "TraceBuffer", "new_trace_id"]
+__all__ = ["PROFILER_PREFIX", "Span", "Trace", "TraceBuffer", "new_trace_id",
+           "stage"]
 
 _now = time.perf_counter
+PROFILER_PREFIX = "pg."
+
+
+class _NoEvent:
+    """Stands in for ``TraceAnnotation`` where JAX is not installed."""
+
+    def __init__(self, name: str):
+        pass
+
+    def __enter__(self) -> None:
+        pass
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+@functools.lru_cache(maxsize=None)
+def _event_type():
+    """``jax.profiler.TraceAnnotation``, imported at the first span that
+    needs it: jax-free clients load this module too (``Trace.from_dict``)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return _NoEvent
+    return TraceAnnotation
 
 
 def new_trace_id() -> str:
@@ -42,11 +79,16 @@ class Span:
         with trace.span("plan") as sp:
             plan = plan_pattern(...)
             sp.annotate(steps=len(plan.mask_steps))
+
+    Entering restarts the clock and opens the profiler event
+    ``"pg." + name``; leaving stops both, so ``t0``/``t1`` and the event
+    cover the same interval.  ``trace`` is None for a detached span
+    (``stage``), which has no children.
     """
 
-    __slots__ = ("name", "t0", "t1", "attrs", "children", "_trace")
+    __slots__ = ("name", "t0", "t1", "attrs", "children", "_trace", "_event")
 
-    def __init__(self, name: str, trace: "Trace",
+    def __init__(self, name: str, trace: Optional["Trace"] = None,
                  t0: Optional[float] = None):
         self.name = name
         self.t0 = _now() if t0 is None else t0
@@ -54,12 +96,17 @@ class Span:
         self.attrs: Dict[str, Any] = {}
         self.children: List["Span"] = []
         self._trace = trace
+        self._event = None
 
     def __enter__(self) -> "Span":
+        self._event = _event_type()(PROFILER_PREFIX + self.name)
+        self._event.__enter__()
+        self.t0 = _now()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.finish()
+        self._event.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
 
@@ -92,6 +139,18 @@ class Span:
         if self.children:
             d["spans"] = [c.to_dict() for c in self.children]
         return d
+
+
+def stage(name: str) -> Span:
+    """A detached span for ``with``: times one stage and holds its profiler
+    event, for stages timed once and copied into traces afterwards::
+
+        with stage("plan") as st:
+            plans = ...
+        for tr in traces:
+            tr.add_span(st.name, st.t0, st.t1)
+    """
+    return Span(name)
 
 
 class Trace:

@@ -41,7 +41,11 @@ Two pieces, both policy-free about caches (the ``Service`` owns those):
   that stays dry for the grace means every in-flight client is blocked
   on this very batch and the rest of the window would be pure stall.
   Single worker by design: device work serializes anyway, and one consumer
-  makes version reads and cache updates race-free.
+  makes version reads and cache updates race-free.  The worker writes
+  three profiler events (docs/ARCHITECTURE.md §13): ``pg.sched.idle``
+  while it blocks on an empty queue, ``pg.sched.window`` over the
+  coalescing window (the ``pg_sched_window_wait_ms`` histogram reads the
+  same timing) and ``pg.batch`` over the batch's execution.
 """
 from __future__ import annotations
 
@@ -51,6 +55,7 @@ import time
 from typing import Callable, Dict, List, Optional, Sequence
 
 from repro.kernels.bitmap_query.ops import bucketed_q
+from repro.obs.trace import stage
 from repro.query import execute_plan, execute_plan_with_masks
 
 __all__ = ["execute_coalesced", "MicroBatcher"]
@@ -194,50 +199,62 @@ class MicroBatcher:
         self._worker.join(timeout=timeout)
 
     # ---------------------------------------------------------------- worker
+    def _next(self):
+        """The next request, blocking on an empty queue inside the
+        profiler event ``pg.sched.idle`` (the device idles for want of
+        work there, not for the host)."""
+        try:
+            return self._queue.get_nowait()
+        except queue.Empty:
+            with stage("sched.idle"):
+                return self._queue.get()
+
     def _loop(self) -> None:
         while True:
-            first = self._queue.get()
+            first = self._next()
             if first is self._SENTINEL:
                 return
             batch = [first]
             stop = False
-            t_first = time.monotonic()
-            # adaptive window: an empty queue means nothing can coalesce —
-            # skip the window entirely (c=1 pays zero batching latency);
-            # a non-empty queue means pressure, so the window opens and
-            # late arrivals join the batch
-            open_window = not (self.adaptive and self._queue.empty())
-            deadline = time.monotonic() + (self.window_s if open_window else 0.0)
-            while open_window and len(batch) < self.max_batch:
-                # clamp: under load the deadline may already be in the past,
-                # and a negative timeout must never reach the queue wait
-                remaining = max(0.0, deadline - time.monotonic())
-                try:
-                    # remaining == 0 (window_ms=0 or expired) still drains
-                    # whatever is already queued, without blocking
-                    if remaining == 0.0:
-                        req = self._queue.get_nowait()
-                    elif self.adaptive:
-                        # arrivals that will coalesce land µs apart; a
-                        # queue that stays empty for a full grace period
-                        # means nothing else is coming this window (a
-                        # closed-loop client set is blocked on THIS batch)
-                        # — execute instead of burning the rest of it
-                        req = self._queue.get(
-                            timeout=min(remaining, self.grace_s))
-                    else:
-                        req = self._queue.get(timeout=remaining)
-                except queue.Empty:
-                    break
-                if req is self._SENTINEL:
-                    stop = True
-                    break
-                batch.append(req)
+            with stage("sched.window") as window:
+                # adaptive window: an empty queue means nothing can
+                # coalesce — skip the window entirely (c=1 pays zero
+                # batching latency); a non-empty queue means pressure, so
+                # the window opens and late arrivals join the batch
+                open_window = not (self.adaptive and self._queue.empty())
+                deadline = time.monotonic() + (self.window_s if open_window else 0.0)
+                while open_window and len(batch) < self.max_batch:
+                    # clamp: under load the deadline may already be in the
+                    # past, and a negative timeout must never reach the
+                    # queue wait
+                    remaining = max(0.0, deadline - time.monotonic())
+                    try:
+                        # remaining == 0 (window_ms=0 or expired) still
+                        # drains whatever is already queued, without blocking
+                        if remaining == 0.0:
+                            req = self._queue.get_nowait()
+                        elif self.adaptive:
+                            # arrivals that will coalesce land µs apart; a
+                            # queue that stays empty for a full grace period
+                            # means nothing else is coming this window (a
+                            # closed-loop client set is blocked on THIS
+                            # batch) — execute instead of burning the rest
+                            req = self._queue.get(
+                                timeout=min(remaining, self.grace_s))
+                        else:
+                            req = self._queue.get(timeout=remaining)
+                    except queue.Empty:
+                        break
+                    if req is self._SENTINEL:
+                        stop = True
+                        break
+                    batch.append(req)
             if self._m_occupancy is not None:
                 self._m_occupancy.observe(len(batch))
-                self._m_wait.observe((time.monotonic() - t_first) * 1e3)
+                self._m_wait.observe((window.t1 - window.t0) * 1e3)
             try:
-                self._execute_batch(batch)
+                with stage("batch"):
+                    self._execute_batch(batch)
             except Exception as e:  # noqa: BLE001 — keep the worker alive
                 # the callback contract says "never raise"; if it does,
                 # fail the batch's futures instead of hanging their clients

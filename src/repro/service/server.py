@@ -52,17 +52,27 @@ Responses echo the request ``id`` (queries resolve out of order —
 result-cache fastpath hits overtake executing batches); errors travel as
 ``{"ok": false, "error": {type, message}}`` and fail only their own
 request.  A malformed frame kills just that session.
+
+A query or sample reply first waits for the result's device arrays
+(span ``device.wait``), then encodes them (span ``serialize``: device
+pack, device-to-host copy, framing), so the wire's own time is not
+propagation's.  The session thread's handling of a received query or
+sample frame writes the profiler event ``pg.submit``; blocking reads,
+accepts and writer-queue waits write none (docs/ARCHITECTURE.md §13).
 """
 from __future__ import annotations
 
+import functools
 import queue
 import socket
 import threading
 import time
-from typing import Dict, Optional
+from typing import Callable, Dict, Optional
+
+import jax
 
 from repro.obs import metrics as obs_metrics
-from repro.obs.trace import Trace
+from repro.obs.trace import Trace, stage
 from repro.service import wire
 from repro.service.service import Service
 
@@ -323,10 +333,12 @@ class PGServer:
         t0 = time.perf_counter()
         try:
             if op == "query":
-                self._op_query(sess, rid, header)
+                with stage("submit"):
+                    self._op_query(sess, rid, header)
                 return  # response rides the future callback
             if op == "sample":
-                self._op_sample(sess, rid, header, arrays)
+                with stage("submit"):
+                    self._op_sample(sess, rid, header, arrays)
                 return  # response rides the future callback
             handler = getattr(self, f"_op_{op}", None)
             if handler is None:
@@ -359,30 +371,37 @@ class PGServer:
                                   impl=header.get("impl"), trace=tr)
         with sess.plock:
             sess.pending[rid] = fut
+        fut.add_done_callback(functools.partial(
+            self._respond, sess, rid, tr, "result", wire.result_to_wire))
 
-        def _respond(f) -> None:
-            with sess.plock:
-                sess.pending.pop(rid, None)
-            err = f.exception()
-            if err is not None:
-                hdr = {"id": rid, "ok": False, "error": wire.exc_to_wire(err)}
-                if tr is not None:
-                    hdr["trace"] = tr.finish().to_dict()
-                sess.send(hdr)
-                return
-            t0 = time.perf_counter()
-            meta, out = wire.result_to_wire(f.result())
-            t1 = time.perf_counter()
-            hdr = {"id": rid, "ok": True, "result": meta}
+    @staticmethod
+    def _respond(sess: _Session, rid, tr: Optional[Trace], key: str,
+                 to_wire: Callable, f) -> None:
+        """Future callback: the reply to one query or sample request.
+        Waits for the result's device arrays (``device.wait``) before
+        encoding them (``serialize``), so each span times one thing."""
+        with sess.plock:
+            sess.pending.pop(rid, None)
+        err = f.exception()
+        if err is not None:
+            hdr = {"id": rid, "ok": False, "error": wire.exc_to_wire(err)}
             if tr is not None:
-                tr.add_span("serialize", t0, t1)
-                tr.root.t1 = t1  # extend the root over serialization; the
-                # service pushed this trace into its ring at resolve time,
-                # and rings hold live objects, so the span is visible there
-                hdr["trace"] = tr.to_dict()
-            sess.send(hdr, out)
-
-        fut.add_done_callback(_respond)
+                hdr["trace"] = tr.finish().to_dict()
+            sess.send(hdr)
+            return
+        with stage("device.wait") as waited:
+            res = jax.block_until_ready(f.result())
+        with stage("serialize") as encoded:
+            meta, out = to_wire(res)
+        hdr = {"id": rid, "ok": True, key: meta}
+        if tr is not None:
+            tr.add_span("device.wait", waited.t0, waited.t1)
+            tr.add_span("serialize", encoded.t0, encoded.t1)
+            tr.root.t1 = encoded.t1  # extend the root over the reply; the
+            # service pushed this trace into its ring at resolve time, and
+            # rings hold live objects, so the spans are visible there
+            hdr["trace"] = tr.to_dict()
+        sess.send(hdr, out)
 
     def _op_sample(self, sess: _Session, rid, header: Dict, arrays) -> None:
         """Fused neighborhood sampling over the wire (§15).  Seeds arrive
@@ -407,33 +426,11 @@ class PGServer:
             deterministic=bool(header.get("deterministic", True)), trace=tr)
         with sess.plock:
             sess.pending[rid] = fut
-
-        def _respond(f) -> None:
-            with sess.plock:
-                sess.pending.pop(rid, None)
-            err = f.exception()
-            if err is not None:
-                hdr = {"id": rid, "ok": False, "error": wire.exc_to_wire(err)}
-                if tr is not None:
-                    hdr["trace"] = tr.finish().to_dict()
-                sess.send(hdr)
-                return
-            t0 = time.perf_counter()
-            meta, out = wire.blocks_to_wire(f.result())
-            t1 = time.perf_counter()
-            hdr = {"id": rid, "ok": True, "sample": meta}
-            if tr is not None:
-                tr.add_span("serialize", t0, t1)
-                tr.root.t1 = t1
-                hdr["trace"] = tr.to_dict()
-            sess.send(hdr, out)
-
-        fut.add_done_callback(_respond)
+        fut.add_done_callback(functools.partial(
+            self._respond, sess, rid, tr, "sample", wire.blocks_to_wire))
 
     # sync ops: return (header fields, arrays) --------------------------------
     def _op_ping(self, header, arrays):
-        import jax
-
         return {"pong": True, "devices": len(jax.devices())}, ()
 
     def _op_graphs(self, header, arrays):

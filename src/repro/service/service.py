@@ -31,7 +31,7 @@ import numpy as np
 
 from repro.obs import metrics as obs_metrics
 from repro.obs.metrics import SIZE_BUCKETS, MetricsRegistry, render_prometheus
-from repro.obs.trace import Trace, TraceBuffer
+from repro.obs.trace import Trace, TraceBuffer, stage
 from repro.overlay.delta import overlaps, pattern_refs
 from repro.query import Pattern, execute_plan, parse, plan_pattern
 from repro.service.cache import LRUCache
@@ -240,15 +240,14 @@ class Service:
             # uniform closed-service contract: even a pattern the result
             # cache could answer raises, like every cache miss would
             raise RuntimeError("scheduler is closed")
-        t0 = time.perf_counter()
-        canonical, ast = self._canon(pattern)
-        t1 = time.perf_counter()
+        with stage("parse") as parsed:
+            canonical, ast = self._canon(pattern)
         tr = trace
         if tr is None and self.config.trace_buffer > 0:
             tr = Trace("query")
         if tr is not None:
             tr.annotate(graph=graph, pattern=canonical)
-            tr.add_span("parse", t0, t1)
+            tr.add_span("parse", parsed.t0, parsed.t1)
         fut: Future = Future()
         self._bump("submitted")
         if self.config.submit_fastpath:
@@ -256,13 +255,14 @@ class Service:
                 # entry liveness is maintained by overlap purging, not a
                 # version key: a hit here may have been cached several
                 # (non-overlapping) writes ago and is still exact (§11)
-                hit = self.result_cache.get((graph, canonical, impl))
+                with stage("cache") as probe:
+                    hit = self.result_cache.get((graph, canonical, impl))
                 if hit is not None:
                     self._bump("result_hits")
                     self._bump("fastpath_hits")
                     self._bump("completed")
                     if tr is not None:
-                        tr.add_span("cache", t1, time.perf_counter(),
+                        tr.add_span("cache", probe.t0, probe.t1,
                                     hit=True, fastpath=True)
                     fut.set_result(hit[2])
                     if tr is not None:
@@ -625,64 +625,69 @@ class Service:
         ``timings`` (optional mutable dict) receives the group's stage
         endpoints — ``cache``/``plan``/``execute`` → ``(t0, t1)`` in
         ``perf_counter`` seconds plus ``cache_hits`` (canonicals served
-        from cache) — measured ONCE per group; the batch path copies them
-        into every member request's trace."""
-        t_cache0 = time.perf_counter()
+        from cache) — measured ONCE per group, each under its profiler
+        event ``pg.<stage>``; the batch path copies them into every
+        member request's trace.  ``execute`` is host time: from the end
+        of planning until the group's last mask and propagation launch is
+        dispatched; the device's own time lands in the reply's
+        ``device.wait``."""
         outcomes: Dict[str, object] = {}
         todo: Dict[str, Pattern] = {}
-        for canonical, ast in canon_asts.items():
-            hit = self.result_cache.get((graph, canonical, impl))
-            if hit is not None:
-                self._bump("result_hits")
-                outcomes[canonical] = hit[2]
-            else:
-                self._bump("result_misses")
-                todo[canonical] = ast
-        t_cache1 = time.perf_counter()
+        with stage("cache") as cached:
+            for canonical, ast in canon_asts.items():
+                hit = self.result_cache.get((graph, canonical, impl))
+                if hit is not None:
+                    self._bump("result_hits")
+                    outcomes[canonical] = hit[2]
+                else:
+                    self._bump("result_misses")
+                    todo[canonical] = ast
         if timings is not None:
-            timings["cache"] = (t_cache0, t_cache1)
+            timings["cache"] = (cached.t0, cached.t1)
             timings["cache_hits"] = set(outcomes)
         if not todo:
             return outcomes
 
         plans: Dict[str, object] = {}
-        for canonical, ast in todo.items():
-            try:
-                plans[canonical] = self._plan(pg, canonical, ast, impl)
-            except Exception as e:  # noqa: BLE001 — isolated to this request
-                outcomes[canonical] = e
-                self._bump("errors")
-        t_plan1 = time.perf_counter()
+        with stage("plan") as planned:
+            for canonical, ast in todo.items():
+                try:
+                    plans[canonical] = self._plan(pg, canonical, ast, impl)
+                except Exception as e:  # noqa: BLE001 — isolated to this request
+                    outcomes[canonical] = e
+                    self._bump("errors")
         if timings is not None:
-            timings["plan"] = (t_cache1, t_plan1)
+            timings["plan"] = (planned.t0, planned.t1)
         if not plans:
             return outcomes
 
         keys = list(plans)
         results: List[object] = []
         stable = False
-        for attempt in range(3):
-            version = pg.version
-            try:
-                results = self._execute_plans(pg, [plans[c] for c in keys], impl)
-            except Exception as e:  # noqa: BLE001
-                if pg.version != version and attempt < 2:
-                    continue  # a concurrent mutation tore the view — retry
-                # the group itself failed: isolate by per-request execution,
-                # counted so a device fault behind it cannot pass unseen
-                self._bump("group_fallbacks")
-                results = []
-                for c in keys:
-                    try:
-                        results.append(execute_plan(pg, plans[c]))
-                    except Exception as ee:  # noqa: BLE001
-                        results.append(ee)
-                break
-            if pg.version == version:
-                stable = True
-                break  # consistent snapshot — safe to cache
+        with stage("execute") as executed:
+            for attempt in range(3):
+                version = pg.version
+                try:
+                    results = self._execute_plans(pg, [plans[c] for c in keys], impl)
+                except Exception as e:  # noqa: BLE001
+                    if pg.version != version and attempt < 2:
+                        continue  # a concurrent mutation tore the view — retry
+                    # the group itself failed: isolate by per-request
+                    # execution, counted so a device fault behind it
+                    # cannot pass unseen
+                    self._bump("group_fallbacks")
+                    results = []
+                    for c in keys:
+                        try:
+                            results.append(execute_plan(pg, plans[c]))
+                        except Exception as ee:  # noqa: BLE001
+                            results.append(ee)
+                    break
+                if pg.version == version:
+                    stable = True
+                    break  # consistent snapshot — safe to cache
         if timings is not None:
-            timings["execute"] = (t_plan1, time.perf_counter())
+            timings["execute"] = (executed.t0, executed.t1)
         put_keys = []
         for c, res in zip(keys, results):
             if isinstance(res, BaseException):
