@@ -43,17 +43,43 @@ candidate masks are replicated across the mesh in ONE all-gather
 (``_gather_masks``) so the chain propagation's arbitrary src/dst gathers
 run collective-free; masks are tiny (1 byte/entity) next to the stores the
 shard-local stage avoided streaming.
+
+Compacted fixed hops.  A fixed hop whose slot has a relationship step
+touches only that relationship's edges, and the plan already bounds how
+many: ``MaskStep.est_count`` (Σ of exact ``attr_counts``, an upper bound
+under overlap).  ``_finish_propagation`` gives such a hop the static
+capacity ``cap = bucket(est_count)`` (next power of two, at least
+``COMPACT_MIN_CAP``) and ``_propagate`` runs it over ``cap`` edge ids
+instead of all m: one sort of the m keys ``where(mask, iota, m)`` yields
+the hop's ids ascending (sentinel m pads), and every gather and scatter of
+the hop — forward heads, backward tails, the (m,) ``alive`` write-back —
+spans ``cap`` lanes.  The compaction is a sort, not ``nonzero(size=)``,
+whose cumsum-and-bincount lowering is an m-wide scatter-add: the very cost
+removed.  Crossover, measured on one TPU v5e at m = 10⁷, n = 8.6·10⁶: a
+dense 1-hop chain (four m-wide gathers, two m-wide scatter-max, each an
+index sort plus a serial update) takes 448 ms, ≈ 45 ns an edge; a
+compacted one at cap = 2¹⁸ takes 35 ms, of which the m-key sort is 10 ms
+(≈ 1 ns a key) and the cap-wide gathers, scatters and fills ≈ 95 ns a
+lane.  So compaction wins while 1·m + 95·cap < 45·m, cap ≲ 0.46·m;
+``COMPACT_MAX_SHARE`` = 1/4 leaves twice that room for the model's error,
+and larger caps keep the dense hop.  Variable-length hops, hops with no
+relationship step and mesh graphs stay dense.  Plans are cached across
+writes, so a bound can go stale: the program counts each compacted hop's
+mask on the device and ``lax.cond`` runs the whole chain dense unless every
+count fits its cap — the answer is exact either way, bit for bit the dense
+one.
 """
 from __future__ import annotations
 
 import dataclasses
 import operator
-from functools import partial
+from functools import partial, reduce
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core import bitplane
 from repro.core.di import DIGraph
@@ -71,6 +97,15 @@ _M_PLANS = _OBS.counter("pg_exec_plans", "plans run through propagation")
 _M_MASKS = _OBS.counter("pg_exec_mask_steps", "attribute mask steps materialized")
 _M_FUSED = _OBS.counter(
     "pg_exec_fused_masks", "mask steps that rode a fused batched launch")
+_M_COMPACT = _OBS.counter(
+    "pg_exec_compact_hops", "hops dispatched over their compacted edge list")
+_M_DENSE = _OBS.counter(
+    "pg_exec_dense_hops", "hops dispatched over all m edges")
+
+# compacted fixed hops (module docstring): the smallest capacity bucket, and
+# the largest share of m a capacity may take before the dense hop is cheaper
+COMPACT_MIN_CAP = 1024
+COMPACT_MAX_SHARE = 0.25
 
 
 @partial(
@@ -131,16 +166,52 @@ class MatchResult:
         return khop_typed(g, seeds, allowed, k=k)
 
 
+def _fill_get(mask: jax.Array, idx: jax.Array) -> jax.Array:
+    """``mask[idx]`` with out-of-range lanes (the compaction's padding)
+    reading False."""
+    return mask.at[idx].get(mode="fill", fill_value=False)
+
+
+def _hop_edges(g: DIGraph, emask: jax.Array, cap: int, d: int):
+    """The first ``cap`` ids of ``emask``'s edges, ascending, padded with the
+    sentinel m, and their tail and head ends (padding reads n): one sort of
+    the m keys ``where(emask, iota, m)``."""
+    keys = jnp.where(emask, jnp.arange(g.m, dtype=jnp.int32), jnp.int32(g.m))
+    ids = lax.sort(keys, is_stable=False)[:cap]
+    src = g.src.at[ids].get(mode="fill", fill_value=g.n)
+    dst = g.dst.at[ids].get(mode="fill", fill_value=g.n)
+    return (ids, src, dst) if d == 1 else (ids, dst, src)
+
+
 @partial(jax.jit, static_argnames=("hops",))
 def _propagate(
     g: DIGraph,
     cands: Tuple[jax.Array, ...],
     emasks: Tuple[jax.Array, ...],
-    hops: Tuple[Tuple[int, int, int], ...],
+    hops: Tuple[Tuple[int, int, int, int], ...],
 ):
     """Forward/backward chain propagation (static hop structure ⇒ fully
     unrolled, one XLA program for the whole pattern).  ``hops`` carries one
-    ``(direction, lo, hi)`` per hop; ``hi == -1`` means unbounded.
+    ``(direction, lo, hi, cap)`` per hop; ``hi == -1`` means unbounded;
+    ``cap > 0`` runs a fixed hop over its compacted edge list of ``cap``
+    lanes (module docstring), ``0`` over all m edges.
+
+    The compacted chain holds only while every compacted hop's mask has at
+    most ``cap`` edges; otherwise ``lax.cond`` runs the dense chain, so a
+    stale bound costs time, never an edge.
+    """
+    caps = [(i, cap) for i, (_, _, _, cap) in enumerate(hops) if cap]
+    if not caps:
+        return _chain(g, cands, emasks, hops)
+    fits = reduce(operator.and_, [
+        jnp.sum(emasks[i], dtype=jnp.int32) <= cap for i, cap in caps])
+    dense = tuple((d, lo, hi, 0) for d, lo, hi, _ in hops)
+    return lax.cond(fits, partial(_chain, hops=hops),
+                    partial(_chain, hops=dense), g, cands, emasks)
+
+
+def _chain(g: DIGraph, cands, emasks, hops):
+    """The chain propagation itself, traced inside ``_propagate``.
 
     Fixed hops ((d, 1, 1) — the original math):
       forward:  f_0 = c_0;  f_i = heads(A_i ∧ f_{i-1}[tail])
@@ -148,7 +219,9 @@ def _propagate(
                 b_{i-1} = tails(alive_i)
     where A_i is the locally-consistent edge set of hop i and tail/head
     follow each hop's direction.  b_i = position-i vertices on a full match;
-    alive_i = hop-i edges on a full match.
+    alive_i = hop-i edges on a full match.  A compacted hop computes the
+    same sets over its edge list; since f_i ⊆ c_i and b_i ⊆ f_i, it reads
+    A_i ∧ f_{i-1}[tail] as f_{i-1}[tail] ∧ c_i[head] on the hop's edges.
 
     Variable-length hops run the module-docstring walk algebra through
     ``repro.traverse.frontier_step``: bounded hops keep exact-step frontier
@@ -157,15 +230,21 @@ def _propagate(
     to no slot) and union into the vertex mask only.
     """
     h = len(hops)
-    ends = [(g.src, g.dst) if d == 1 else (g.dst, g.src) for d, _, _ in hops]
+    ends = [(g.src, g.dst) if d == 1 else (g.dst, g.src) for d, *_ in hops]
 
     fwd = [cands[0]]
     local = [None] * h  # fixed hops: locally-consistent edge sets
+    sub = [None] * h  # compacted hops: (ids, tail, head, forward survivors)
     flayers = [None] * h  # bounded var hops: forward exact-step layers
     fclosure = [None] * h  # unbounded var hops: forward closure
-    for i, (d, lo, hi) in enumerate(hops):
+    for i, (d, lo, hi, cap) in enumerate(hops):
         tail, head = ends[i]
-        if (lo, hi) == (1, 1):
+        if cap:
+            ids, t, hd = _hop_edges(g, emasks[i], cap, d)
+            a = _fill_get(fwd[i], t) & _fill_get(cands[i + 1], hd)
+            sub[i] = (ids, t, hd, a)
+            fwd.append(jnp.zeros_like(cands[i + 1]).at[hd].max(a, mode="drop"))
+        elif (lo, hi) == (1, 1):
             local[i] = induce_edge_mask_directed(
                 g, cands[i], cands[i + 1], emasks[i], d)
             a = local[i] & fwd[i][tail]
@@ -190,9 +269,14 @@ def _propagate(
     alive = [None] * h
     interiors = []  # var-hop walk vertices that belong to no slot
     for i in range(h - 1, -1, -1):
-        d, lo, hi = hops[i]
+        d, lo, hi, cap = hops[i]
         tail, head = ends[i]
-        if (lo, hi) == (1, 1):
+        if cap:
+            ids, t, hd, a = sub[i]
+            al = a & _fill_get(back[i + 1], hd)
+            alive[i] = jnp.zeros((g.m,), jnp.bool_).at[ids].set(al, mode="drop")
+            back[i] = jnp.zeros_like(fwd[i]).at[t].max(al, mode="drop")
+        elif (lo, hi) == (1, 1):
             al = local[i] & fwd[i][tail] & back[i + 1][head]
             alive[i] = al
             back[i] = jnp.zeros_like(fwd[i]).at[tail].max(al)
@@ -513,6 +597,28 @@ def execute_plan_with_masks(
     return _finish_propagation(pg, plan, g, cands, emasks)
 
 
+def _hop_caps(pg, plan: Plan, g: DIGraph) -> Tuple[int, ...]:
+    """Per hop, the compacted edge list's capacity, or 0 for the dense hop
+    (module docstring): read off the plan's relationship estimates, no store
+    access and no device sync.  Counts each hop by the path chosen."""
+    est = {}  # mesh graphs keep every hop dense
+    if getattr(pg, "mesh", None) is None:
+        est = {s.slot: s.est_count for s in plan.mask_steps if s.kind == "edge"}
+    caps = []
+    for slot, e in enumerate(plan.pattern.edges):
+        cap = 0
+        if e.is_fixed and slot in est:
+            cap = max(COMPACT_MIN_CAP, 1 << max(est[slot] - 1, 0).bit_length())
+            if cap > COMPACT_MAX_SHARE * g.m:
+                cap = 0
+        caps.append(cap)
+    if _obs_enabled():
+        compact = sum(1 for c in caps if c)
+        _M_COMPACT.inc(compact)
+        _M_DENSE.inc(len(caps) - compact)
+    return tuple(caps)
+
+
 def _finish_propagation(pg, plan: Plan, g: DIGraph, cands, emasks) -> MatchResult:
     """Shared stage-3 tail: mesh replication of the combined per-slot masks
     (no-op single-device), the static-hop chain propagation, and result
@@ -523,8 +629,8 @@ def _finish_propagation(pg, plan: Plan, g: DIGraph, cands, emasks) -> MatchResul
         emasks = _gather_masks(emasks, mesh)
 
     hops = tuple(
-        (e.direction, e.lo, -1 if e.hi is None else e.hi)
-        for e in plan.pattern.edges
+        (e.direction, e.lo, -1 if e.hi is None else e.hi, cap)
+        for e, cap in zip(plan.pattern.edges, _hop_caps(pg, plan, g))
     )
     vmask, emask, node_masks, alive = _propagate(
         g, tuple(cands), emasks=tuple(emasks), hops=hops)

@@ -1,5 +1,7 @@
 """Pattern engine: parser round-trips, planner selectivity decisions, and
 match() ≡ hand-composed mask pipelines on random graphs, all DIP backends."""
+import re
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -363,3 +365,210 @@ def test_query_any_batched_consistent(pg):
         for impl in ("scan", "kernel"):
             alt = np.asarray(pg._vstore.query_any_batched(queries, impl=impl))
             assert (alt == batched).all(), impl
+
+
+# ------------------------------------------------------ compacted fixed hops
+def _compact_graph(seed=3, m=20_000, n=5_000, rels=20, mesh=None):
+    """A graph big enough that a relationship's bucket (≈ m/rels → 1024)
+    sits under the compaction cutoff (m/4): the host path compacts."""
+    rng = np.random.default_rng(seed)
+    pg = PropGraph(backend="arr", mesh=mesh).add_edges_from(
+        rng.integers(0, n, m), rng.integers(0, n, m))
+    nodes = np.asarray(pg.graph.node_map)
+    pg.add_node_labels(nodes, rng.choice(["a", "b", "c"], len(nodes)))
+    es, ed = np.asarray(pg.graph.src), np.asarray(pg.graph.dst)
+    pg.add_edge_relationships(
+        nodes[es], nodes[ed], rng.choice([f"r{i}" for i in range(rels)], len(es)))
+    return pg, rng
+
+
+def _multi_rel(pg, rng):
+    """Give a third of r1's edges r2 as well: edges holding both."""
+    nodes = np.asarray(pg.graph.node_map)
+    es, ed = np.asarray(pg.graph.src), np.asarray(pg.graph.dst)
+    r1 = np.flatnonzero(np.asarray(pg.query_relationships(["r1"])))[::3]
+    pg.add_edge_relationships(nodes[es[r1]], nodes[ed[r1]], ["r2"] * len(r1))
+
+
+def _tombstones(pg, rng):
+    nodes = np.asarray(pg.graph.node_map)
+    es, ed = np.asarray(pg.graph.src), np.asarray(pg.graph.dst)
+    pg.delete_vertices(rng.choice(nodes, 200, replace=False))
+    kill = rng.choice(len(es), 500, replace=False)
+    pg.delete_edges(nodes[es[kill]], nodes[ed[kill]])
+
+
+def _overlay(pg, rng):
+    nodes = np.asarray(pg.graph.node_map)
+    s, d = rng.choice(nodes, 400), rng.choice(nodes, 400)
+    pg.insert_edges(s, d)
+    pg.add_edge_relationships(s, d, ["r1"] * len(s))
+
+
+COMPACT_CASES = {
+    "1-hop forward": ("(x:a)-[:r1]->(y:b)", None),
+    "1-hop backward": ("(x:a)<-[:r2]-(y:b)", None),
+    "2-hop": ("(x:a)-[:r1]->(y)-[:r3]->(z:c)", None),
+    "multi-relationship hop": ("(x)-[:r1|r2]->(y:b)", _multi_rel),
+    "tombstoned vertices and edges": ("(x:a)-[:r1]->(y)<-[:r4]-(z:b)", _tombstones),
+    "overlay with delta edges": ("(x:a)-[:r1]->(y:b|c)", _overlay),
+    "empty relationship": ("(x:a)-[:nope]->(y)", None),
+}
+
+
+def _propagate_args(monkeypatch, pg, plan):
+    """The arguments the executor hands ``_propagate`` for ``plan``."""
+    from repro.query import executor
+
+    seen = {}
+    real = executor._propagate
+
+    def spy(g, cands, emasks, hops):
+        seen.update(g=g, cands=cands, emasks=emasks, hops=hops)
+        return real(g, cands, emasks=emasks, hops=hops)
+
+    monkeypatch.setattr(executor, "_propagate", spy)
+    executor.execute_plan(pg, plan)
+    monkeypatch.undo()
+    return seen
+
+
+def _run(args, hops):
+    from repro.query.executor import _propagate
+
+    out = _propagate(args["g"], args["cands"], emasks=args["emasks"], hops=hops)
+    return [np.asarray(x) for x in (out[0], out[1], *out[2], *out[3])]
+
+
+def _dense(hops):
+    return tuple((d, lo, hi, 0) for d, lo, hi, _ in hops)
+
+
+def _assert_same(got, want):
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and (a == b).all()
+
+
+@pytest.mark.parametrize("case", sorted(COMPACT_CASES))
+def test_compacted_propagation_equals_dense(monkeypatch, case):
+    """Every fixed relationship hop takes its compacted edge list on the
+    host path, and the answer is bitwise the dense one's."""
+    text, mutate = COMPACT_CASES[case]
+    pg, rng = _compact_graph()
+    if mutate is not None:
+        mutate(pg, rng)
+    args = _propagate_args(monkeypatch, pg, plan_pattern(pg, parse(text)))
+    assert all(cap >= 1024 for *_, cap in args["hops"]), args["hops"]
+    _assert_same(_run(args, args["hops"]), _run(args, _dense(args["hops"])))
+
+
+def test_stale_bound_takes_the_dense_branch(monkeypatch):
+    """A plan made before a write keeps its old ``est_count``: once the
+    relationship outgrows the cap, the program runs the dense chain and
+    stays exact; the compacted chain alone would have dropped edges."""
+    from repro.query.executor import _chain
+
+    pg, rng = _compact_graph()
+    plan = plan_pattern(pg, parse("(x)-[:r1]->(y)"))
+    (cap,) = [c for *_, c in _propagate_args(monkeypatch, pg, plan)["hops"]]
+    nodes = np.asarray(pg.graph.node_map)
+    s, d = rng.choice(nodes, cap), rng.choice(nodes, cap)
+    pg.insert_edges(s, d)
+    pg.add_edge_relationships(s, d, ["r1"] * len(s))
+    args = _propagate_args(monkeypatch, pg, plan)  # the stale plan
+    assert args["hops"][0][3] == cap
+    assert int(np.asarray(args["emasks"][0]).sum()) > cap
+    got = _run(args, args["hops"])
+    _assert_same(got, _run(args, _dense(args["hops"])))
+    assert (got[1] == np.asarray(pg.match("(x)-[:r1]->(y)").edge_mask)).all()
+    truncated = _chain(args["g"], args["cands"], args["emasks"], args["hops"])
+    assert int(np.asarray(truncated[1]).sum()) < int(got[1].sum())
+
+
+def _hlo_ops(hlo: str):
+    """Per computation of a lowered HLO module: (scatter update sizes, sort
+    sizes), and the computations reachable from ENTRY without entering
+    branch 0 of a conditional (``lax.cond``'s false branch, here the dense
+    chain)."""
+    comps, cur, entry = {}, None, None
+    for line in hlo.splitlines():
+        hdr = re.match(r"^(ENTRY )?([\w.\-]+) \{$", line)
+        if hdr:
+            cur = hdr.group(2)
+            comps[cur] = []
+            entry = cur if hdr.group(1) else entry
+        elif line == "}":
+            cur = None
+        elif cur is not None:
+            comps[cur].append(line)
+    ops = {}
+    for name, lines in comps.items():
+        size, scatters, sorts = {}, [], []
+        for line in lines:
+            d = re.match(r"\s*(?:ROOT )?([\w.\-]+) = \w+\[([\d,]*)\]", line)
+            if d:
+                size[d.group(1)] = int(np.prod([int(x) for x in d.group(2).split(",") if x]))
+            s = re.search(r" scatter\(([^)]*)\)", line)
+            if s:
+                scatters.append(size[s.group(1).split(", ")[2]])
+            if re.search(r" sort\(", line):
+                sorts.append(size[d.group(1)])
+        ops[name] = (scatters, sorts)
+    live, todo = set(), [entry]
+    while todo:
+        name = todo.pop()
+        if name in live:
+            continue
+        live.add(name)
+        for line in comps[name]:
+            todo += re.findall(
+                r"(?:to_apply|calls|body|condition|true_computation|false_computation)"
+                r"=([\w.\-]+)", line)
+            for br in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                todo += [b.strip() for b in br.split(",")][1:]
+    return ops, live
+
+
+def test_compacted_hop_program_has_no_m_wide_scatter(monkeypatch):
+    """Outside the dense fallback, a compacted 1-hop ``_propagate`` holds no
+    scatter whose updates span m elements and one sort of m keys."""
+    from repro.query.executor import _propagate
+
+    pg, _ = _compact_graph()
+    args = _propagate_args(monkeypatch, pg, plan_pattern(pg, parse("(x:a)-[:r1]->(y:b)")))
+    m = pg.graph.m
+    hlo = _propagate.lower(args["g"], args["cands"], emasks=args["emasks"],
+                           hops=args["hops"]).as_text(dialect="hlo")
+    ops, live = _hlo_ops(hlo)
+    scatters = [u for c in live for u in ops[c][0]]
+    sorts = [s for c in live for s in ops[c][1]]
+    assert scatters and max(scatters) < m, scatters
+    assert sorts.count(m) == 1, sorts
+    # the guard can see an m-wide scatter: the dense fallback holds them
+    assert any(u == m for sc, _ in ops.values() for u in sc)
+
+
+@pytest.mark.parametrize("text,mesh,compact,dense", [
+    ("(x:a)-[:r1]->(y:b)", False, 1, 0),  # relationship hop
+    ("(x:a)-[]->(y:b)", False, 0, 1),  # unconstrained hop
+    ("(x:a)-[:r1]->(y)-[:r2*1..2]->(z)", False, 1, 1),  # fixed + variable-length
+    ("(x:a)-[:r1]->(y:b)", True, 0, 1),  # mesh graphs stay dense
+])
+def test_hop_path_counters(text, mesh, compact, dense):
+    from repro.launch.mesh import make_entity_mesh
+    from repro.obs import metrics
+
+    pg, _ = _compact_graph(mesh=make_entity_mesh() if mesh else None)
+    c = metrics.GLOBAL.counter("pg_exec_compact_hops")
+    d = metrics.GLOBAL.counter("pg_exec_dense_hops")
+    prev = metrics.set_enabled(True)
+    try:
+        c0, d0 = c.value(), d.value()
+        res = pg.match(text)
+        assert (c.value() - c0, d.value() - d0) == (compact, dense)
+    finally:
+        metrics.set_enabled(prev)
+    if mesh:  # and the mesh answer is the single-device one
+        single, _ = _compact_graph()
+        assert (np.asarray(res.edge_mask) == np.asarray(single.match(text).edge_mask)).all()
