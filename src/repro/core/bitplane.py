@@ -20,6 +20,7 @@ The byte path stays available for one release behind
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 from typing import Iterator, Optional
 
@@ -32,7 +33,8 @@ WORD = 32  # bits per packed word
 __all__ = [
     "WORD", "n_words", "packed_default", "byte_masks",
     "pack_bits_host", "unpack_bits_host",
-    "pack_mask", "unpack_mask", "or_reduce", "or_allreduce",
+    "pack_mask", "unpack_mask", "census", "nonzero_words",
+    "or_reduce", "or_allreduce",
 ]
 
 # None → consult the env var; True/False → explicit override (context manager).
@@ -124,6 +126,37 @@ def unpack_mask(words: jax.Array, n: int) -> jax.Array:
     bits = (words[..., None] >> shifts) & jnp.uint32(1)
     flat = bits.reshape(words.shape[:-1] + (words.shape[-1] * WORD,))
     return flat[..., :n].astype(bool)
+
+
+def _nonzero_words(words: jax.Array, cap: int) -> jax.Array:
+    w = words.shape[0]
+    keys = jnp.where(words != 0, jnp.arange(w, dtype=jnp.int32), w)
+    keys, words = jax.lax.sort((keys, words), num_keys=1)
+    return jnp.concatenate([keys[:cap].astype(jnp.uint32), words[:cap]])
+
+
+@functools.partial(jax.jit, static_argnames="cap")
+def nonzero_words(words: jax.Array, cap: int) -> jax.Array:
+    """The first ``cap`` nonzero words of a packed mask with their indices:
+    ``(2·min(cap, W),)`` uint32, the indices (ascending) then the words.
+
+    One key-value sort of the W words, nonzero ones keyed by their index
+    and the rest by W, so it gathers nothing: on a TPU a sort of 10⁵ keys
+    costs far less than cap-wide gathers.  Slots past the nonzero words
+    hold index W and word 0, and expand to no positions.
+    """
+    return _nonzero_words(words, cap)
+
+
+@functools.partial(jax.jit, static_argnames="cap")
+def census(mask: jax.Array, cap: int):
+    """A (n,) device bool mask → ``(words, count, head)`` in one launch: its
+    packed words, its popcount (int32), and ``nonzero_words(words, cap)``.
+    A caller reads ``count`` back to choose how to ship the mask; when the
+    mask has no more than ``cap`` set bits, ``head`` already holds them."""
+    words = pack_mask(mask)
+    count = jnp.sum(jax.lax.population_count(words), dtype=jnp.int32)
+    return words, count, _nonzero_words(words, cap)
 
 
 def or_reduce(words: jax.Array, axis: int = 0) -> jax.Array:

@@ -39,7 +39,8 @@ Request ops (header ``{"op": ..., "id": ...}`` + optional array blobs):
                                        so the scheduler coalesces sample
                                        requests across sessions into one
                                        batched launch; blocks return as
-                                       packed masks + index arrays
+                                       masks (ids or packed bits) +
+                                       index arrays
     snapshot {graph, name?}          pin a frozen snapshot, register it
     fork_view {graph, name?}         writable copy-on-write view
     drop_view {name}                 unregister a snapshot/fork
@@ -54,11 +55,13 @@ result-cache fastpath hits overtake executing batches); errors travel as
 request.  A malformed frame kills just that session.
 
 A query or sample reply first waits for the result's device arrays
-(span ``device.wait``), then encodes them (span ``serialize``: device
-pack, device-to-host copy, framing), so the wire's own time is not
-propagation's.  The session thread's handling of a received query or
-sample frame writes the profiler event ``pg.submit``; blocking reads,
-accepts and writer-queue waits write none (docs/ARCHITECTURE.md §13).
+(span ``device.wait``), then encodes them (span ``serialize``: each
+mask's device census and, when sparse, its set positions, then the
+device-to-host copy; the session's writer thread frames and sends), so
+the wire's own time is not propagation's.  The session thread's handling
+of a received query or sample frame writes the profiler event
+``pg.submit``; blocking reads, accepts and writer-queue waits write none
+(docs/ARCHITECTURE.md §13).
 """
 from __future__ import annotations
 

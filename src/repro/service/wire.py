@@ -7,7 +7,7 @@ docs/ARCHITECTURE.md §9).  This module is that message format for the
 analytics service — one codec shared by ``server.py`` and ``client.py`` so
 the two can never disagree about framing.
 
-Frame layout (all integers big-endian)::
+Frame layout (the frame's integers big-endian)::
 
     MAGIC (4 bytes, b"PGW1")
     payload_len   uint32        # bytes after this field
@@ -17,18 +17,37 @@ Frame layout (all integers big-endian)::
 
 The header is small and human-debuggable JSON; bulk data (masks, id
 arrays, property columns) travels as raw buffers described by per-array
-specs ``{"dtype", "shape"}`` appended by the codec.  Bool arrays are
-``np.packbits(bitorder="little")``-packed on the wire (8× smaller) and
-restored exactly — mask round-trips are bitwise, which the cross-process
-equivalence gate relies on (``pgserve --net --smoke``).  Little-endian bit
-order makes the wire bytes IDENTICAL to the ``core.bitplane`` word plane's
-byte view, so a mask the server already holds packed ships verbatim
-(:class:`PackedMask` — no unpack→repack; ``result_to_wire`` packs device
-masks in one launch each and hands the codec the raw words).
+specs ``{"dtype", "shape"}`` appended by the codec.  A bool array travels
+one of two ways, and comes back as the same plain bool array either way —
+mask round-trips are bitwise, which the cross-process equivalence gate
+relies on (``pgserve --net --smoke``):
+
+* ``{"dtype": "bool", "shape": [n], "enc": "ids", "count": k}`` — a (n,)
+  mask with few set bits (``32·k < n``, where 4k bytes undercut the n/8 of
+  a bitmap) ships as its k set positions, ascending little-endian int32.
+  A typed pattern's reply masks usually are: a hop over one relationship
+  of many touches thousands of entities out of millions.  The receiver
+  validates them (in range, strictly ascending, the count matching the
+  blob) and sets those bits in ``np.zeros(n, bool)``.
+* otherwise ``np.packbits(bitorder="little")``-packed (8× smaller than
+  bytes).  Little-endian bit order makes the wire bytes IDENTICAL to the
+  ``core.bitplane`` word plane's byte view, so a mask the server holds
+  packed ships verbatim (:class:`PackedMask`).
+
+The choice follows the mask's popcount alone.  ``result_to_wire`` takes it
+on the device: one launch per mask packs it, counts its bits and sorts its
+first 2¹⁰ nonzero words to the front (``bitplane.census``); a sparse mask
+with more set bits than that takes a second launch
+(``bitplane.nonzero_words``) whose buffer is the smallest of a few
+per-length capacities that holds the count.  So the device-to-host copy is
+on the order of the set bits, not of n, and the host expands the nonzero
+words to positions.  The counter
+``pg_wire_masks{enc="ids"|"bitmap"}`` counts each mask at encode.
 
 ``recv_msg`` raises ``ConnectionError`` on a clean EOF at a frame
 boundary (peer closed) and ``ProtocolError`` on everything else —
-truncated frames, bad magic, oversized payloads.
+truncated frames, bad magic, oversized payloads, bad array specs or ids,
+bytes past the last blob.
 """
 from __future__ import annotations
 
@@ -36,6 +55,7 @@ import dataclasses
 import json
 import socket
 import struct
+import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -46,6 +66,7 @@ __all__ = [
     "ProtocolError",
     "RemoteError",
     "PackedMask",
+    "SparseMask",
     "encode_msg",
     "send_msg",
     "recv_msg",
@@ -80,6 +101,16 @@ _SENT = _wire_counters("sent")
 _RECEIVED = _wire_counters("received")
 
 
+def _mask_counter(enc: str):
+    from repro.obs.metrics import GLOBAL
+
+    return GLOBAL.counter("pg_wire_masks", "bool masks encoded", enc=enc)
+
+
+_MASKS_IDS = _mask_counter("ids")
+_MASKS_BITMAP = _mask_counter("bitmap")
+
+
 class ProtocolError(RuntimeError):
     """Malformed frame: bad magic, truncated payload, oversized length."""
 
@@ -107,8 +138,34 @@ class PackedMask:
     n: int
 
 
+@dataclasses.dataclass(frozen=True)
+class SparseMask:
+    """A (n,) bool mask held as its set positions: the ``ids`` encoding.
+
+    The codec ships ``ids`` as they are and the receiver sees a plain bool
+    array, like :class:`PackedMask`'s."""
+
+    ids: np.ndarray  # (k,) int32, strictly ascending, each in [0, n)
+    n: int
+
+
+def _ids_cheaper(count: int, n: int) -> bool:
+    """True when k int32 positions take fewer bytes than the n-bit bitmap."""
+    return 32 * count < n
+
+
+def _ids_blob(ids, n: int) -> Tuple[dict, bytes]:
+    ids = np.ascontiguousarray(ids, dtype="<i4")
+    _MASKS_IDS.inc()
+    return ({"dtype": "bool", "shape": [int(n)], "enc": "ids",
+             "count": int(ids.shape[0])}, ids.tobytes())
+
+
 def _pack_array(a) -> Tuple[dict, bytes]:
+    if isinstance(a, SparseMask):
+        return _ids_blob(a.ids, a.n)
     if isinstance(a, PackedMask):
+        _MASKS_BITMAP.inc()
         spec = {"dtype": "bool", "shape": [int(a.n)]}
         nbytes = (int(a.n) + 7) // 8
         words = np.ascontiguousarray(np.asarray(a.words, dtype="<u4"))
@@ -116,6 +173,9 @@ def _pack_array(a) -> Tuple[dict, bytes]:
     a = np.ascontiguousarray(a)
     spec = {"dtype": str(a.dtype), "shape": list(a.shape)}
     if a.dtype == np.bool_:
+        if a.ndim == 1 and _ids_cheaper(np.count_nonzero(a), a.shape[0]):
+            return _ids_blob(np.flatnonzero(a), a.shape[0])
+        _MASKS_BITMAP.inc()
         return spec, np.packbits(a.reshape(-1), bitorder="little").tobytes()
     return spec, a.tobytes()
 
@@ -142,10 +202,32 @@ def _parse_spec(spec) -> Tuple[np.dtype, Tuple[int, ...], int]:
     return dtype, shape, count
 
 
+def _parse_ids_spec(spec: dict) -> Tuple[int, int]:
+    """Validate an untrusted ``ids`` spec → (n, count)."""
+    shape, count = spec.get("shape"), spec.get("count")
+    if (spec.get("enc") != "ids" or spec.get("dtype") != "bool"
+            or not isinstance(shape, list) or len(shape) != 1
+            or type(shape[0]) is not int or type(count) is not int
+            or not 0 <= count <= shape[0] <= MAX_PAYLOAD):
+        raise ProtocolError(f"bad array spec {spec!r}")
+    return shape[0], count
+
+
 def _blob_nbytes(dtype: np.dtype, count: int) -> int:
     if dtype == np.bool_:
         return (count + 7) // 8
     return count * dtype.itemsize
+
+
+def _unpack_ids(n: int, count: int, buf: memoryview) -> np.ndarray:
+    ids = np.frombuffer(buf, dtype="<i4", count=count)
+    if count and (ids[0] < 0 or ids[-1] >= n
+                  or not (ids[1:] > ids[:-1]).all()):
+        raise ProtocolError(
+            f"ids of a ({n},) mask are not strictly ascending in range")
+    mask = np.zeros(n, np.bool_)
+    mask[ids] = True
+    return mask
 
 
 def _unpack_array(dtype: np.dtype, shape: Tuple[int, ...], count: int,
@@ -163,7 +245,7 @@ def encode_msg(header: Dict, arrays: Sequence[np.ndarray] = ()) -> bytes:
     owns the ``"arrays"`` key."""
     specs, blobs = [], []
     for a in arrays:
-        spec, blob = _pack_array(a if isinstance(a, PackedMask)
+        spec, blob = _pack_array(a if isinstance(a, (PackedMask, SparseMask))
                                  else np.asarray(a))
         specs.append(spec)
         blobs.append(blob)
@@ -229,12 +311,22 @@ def recv_msg(sock: socket.socket) -> Tuple[Dict, List[np.ndarray]]:
     arrays: List[np.ndarray] = []
     off = _LEN.size + header_len
     for spec in specs:
-        dtype, shape, count = _parse_spec(spec)
-        n = _blob_nbytes(dtype, count)
-        if off + n > payload_len:
-            raise ProtocolError("array blobs exceed payload")
-        arrays.append(_unpack_array(dtype, shape, count, payload[off:off + n]))
-        off += n
+        if isinstance(spec, dict) and "enc" in spec:
+            n, count = _parse_ids_spec(spec)
+            nbytes = 4 * count
+            if off + nbytes > payload_len:
+                raise ProtocolError("array blobs exceed payload")
+            arrays.append(_unpack_ids(n, count, payload[off:off + nbytes]))
+        else:
+            dtype, shape, count = _parse_spec(spec)
+            nbytes = _blob_nbytes(dtype, count)
+            if off + nbytes > payload_len:
+                raise ProtocolError("array blobs exceed payload")
+            arrays.append(_unpack_array(dtype, shape, count,
+                                        payload[off:off + nbytes]))
+        off += nbytes
+    if off != payload_len:
+        raise ProtocolError(f"{payload_len - off} bytes past the array blobs")
     return header, arrays
 
 
@@ -262,41 +354,119 @@ class WireMatchResult:
         return int(self.edge_mask.sum())
 
 
-def _mask_payload(mask):
-    """Bool device masks pack ON DEVICE into bitplane words and ship as
-    :class:`PackedMask` — the codec's wire bytes without ever
-    materializing the byte-per-entity host copy.  Anything else (host
-    arrays, non-bool) goes through the generic path."""
+# A device mask's census brings back its first _HEAD nonzero words with its
+# popcount, so a mask with no more set bits than that (8 KB) takes one
+# launch.  A sparse mask with more takes a second launch, whose buffer is the
+# smallest of _WORD_CAPS, or of the largest count the ids encoding takes,
+# that holds the count: the copy is at most 4x the 4k bytes shipped (a mask
+# has no more nonzero words than set bits), from at most four programs a
+# mask length.
+_HEAD = 1 << 10
+_WORD_CAPS = (1 << 12, 1 << 14, 1 << 16)
+_warmed: set = set()
+_warm_lock = threading.Lock()
+
+
+def _word_caps(n: int) -> List[int]:
+    """The second-launch buffers a (n,) mask may use, smallest first; none
+    where the head holds every count the ids encoding takes."""
+    top = (n - 1) // 32  # the largest count with _ids_cheaper(count, n)
+    if top <= _HEAD:
+        return []
+    return [c for c in _WORD_CAPS if c < top] + [top]
+
+
+def _warm_nonzero_words(bitplane, words, n: int) -> None:
+    """Compile every buffer of this mask length and placement the first time
+    one is seen, so that a count which lands in a new bucket later compiles
+    nothing: a served cell's warm-up covers them all."""
+    key = (n, words.sharding)
+    with _warm_lock:
+        if key in _warmed:
+            return
+        _warmed.add(key)
+    for cap in _word_caps(n):
+        bitplane.nonzero_words(words, cap=cap)
+
+
+def _positions(pairs: np.ndarray) -> np.ndarray:
+    """``bitplane.nonzero_words`` output → the mask's set positions,
+    ascending (a padding slot's word is 0 and yields none)."""
+    idx, words = np.split(pairs, 2)
+    bits = np.unpackbits(np.ascontiguousarray(words, "<u4")
+                         .view(np.uint8).reshape(-1, 4), axis=1,
+                         bitorder="little")
+    row, bit = np.nonzero(bits)
+    return (idx[row].astype(np.int64) * 32 + bit).astype(np.int32)
+
+
+def _mask_payloads(masks) -> list:
+    """Reply masks as the codec ships them.  A (n,) bool device mask is
+    packed and counted on the device, its first nonzero words gathered in
+    the same launch (``bitplane.census``), and the rest of a sparse mask's
+    in a second (``bitplane.nonzero_words``), so only ~8 bytes a set bit
+    cross to the host, where they expand to the :class:`SparseMask`'s
+    positions; a dense one ships as the :class:`PackedMask` of its words.
+    Anything else (host arrays, non-bool) goes through the codec's generic
+    path."""
     try:
         import jax
 
         from repro.core import bitplane
     except ImportError:  # jax-free client process
-        return np.asarray(mask)
-    if isinstance(mask, jax.Array) and mask.dtype == bool and mask.ndim == 1:
-        n = int(mask.shape[0])
-        return PackedMask(words=np.asarray(bitplane.pack_mask(mask)), n=n)
-    return np.asarray(mask)
+        return [np.asarray(m) for m in masks]
+    out = list(masks)
+    dev = {}
+    for i, m in enumerate(masks):
+        if (isinstance(m, jax.Array) and m.dtype == bool and m.ndim == 1
+                and m.shape[0]):
+            dev[i] = bitplane.census(m, cap=_HEAD)
+            for a in dev[i][1:]:
+                a.copy_to_host_async()
+        else:
+            out[i] = np.asarray(m)
+    staged = {}
+    for i, (words, count, head) in dev.items():
+        n, k = int(masks[i].shape[0]), int(count)
+        _warm_nonzero_words(bitplane, words, n)
+        if not _ids_cheaper(k, n):
+            staged[i] = (words, False, n)
+        elif k <= _HEAD:
+            out[i] = SparseMask(ids=_positions(np.asarray(head)), n=n)
+        else:
+            cap = next(c for c in _word_caps(n) if c >= k)
+            staged[i] = (bitplane.nonzero_words(words, cap=cap), True, n)
+    for a, _, _ in staged.values():
+        a.copy_to_host_async()
+    for i, (a, sparse, n) in staged.items():
+        out[i] = (SparseMask(ids=_positions(np.asarray(a)), n=n) if sparse
+                  else PackedMask(words=np.asarray(a), n=n))
+    return out
 
 
 def result_to_wire(res) -> Tuple[Dict, List[np.ndarray]]:
     """``MatchResult`` → (meta, arrays): masks first, bindings after in
-    ``meta["vars"]`` order.  Masks travel bit-packed end to end."""
+    ``meta["vars"]`` order, each as ids or a bitmap by its popcount."""
     bindings = res.bindings()
     names = sorted(bindings)
-    arrays = [_mask_payload(res.vertex_mask), _mask_payload(res.edge_mask)]
-    arrays.extend(_mask_payload(bindings[k]) for k in names)
+    arrays = _mask_payloads([res.vertex_mask, res.edge_mask]
+                            + [bindings[k] for k in names])
     return {"vars": names}, arrays
 
 
 def _as_bool_mask(a) -> np.ndarray:
     """Normalize a result payload to a (n,) bool array.  The codec already
-    delivers bool (``_unpack_array``); in-process callers that short-circuit
-    the transport may hand back the ``PackedMask`` from ``result_to_wire``
-    (possibly wrapped in a 0-d object array by ``np.asarray``) — unpack it
-    host-side (numpy only, so jax-free clients stay jax-free)."""
+    delivers bool (``recv_msg``); in-process callers that short-circuit the
+    transport may hand back the ``PackedMask`` or ``SparseMask`` from
+    ``result_to_wire`` (possibly wrapped in a 0-d object array by
+    ``np.asarray``) — expand it host-side (numpy only, so jax-free clients
+    stay jax-free)."""
     if isinstance(a, np.ndarray) and a.dtype == object and a.ndim == 0:
         a = a.item()
+    if isinstance(a, SparseMask):
+        mask = np.zeros(a.n, np.bool_)
+        mask[np.asarray(a.ids)] = True
+        return mask
     if isinstance(a, PackedMask):
         words = np.ascontiguousarray(np.asarray(a.words, dtype="<u4"))
         bits = np.unpackbits(words.view(np.uint8), bitorder="little")
@@ -350,16 +520,17 @@ class WireSampledBlock:
 def blocks_to_wire(blocks) -> Tuple[Dict, List[np.ndarray]]:
     """SampledBlock list → (meta, arrays): five arrays per layer in block
     order (src_nodes, dst_nodes, edge_src, edge_dst, edge_mask) — the id/
-    index arrays as int32 blobs, the mask bit-packed by the codec (device
-    masks pack on device, §15's "blocks ship as packed masks + index
-    arrays")."""
+    index arrays as int32 blobs, the mask as ids or a bitmap by the same
+    popcount rule as a match's (§15's "blocks ship as packed masks + index
+    arrays"; a padded block's mask is mostly set, so a bitmap)."""
     arrays: List[np.ndarray] = []
-    for b in blocks:
+    masks = _mask_payloads([b.edge_mask for b in blocks])
+    for b, mask in zip(blocks, masks):
         arrays.append(np.asarray(b.src_nodes, np.int32))
         arrays.append(np.asarray(b.dst_nodes, np.int32))
         arrays.append(np.asarray(b.edge_src, np.int32))
         arrays.append(np.asarray(b.edge_dst, np.int32))
-        arrays.append(_mask_payload(b.edge_mask))
+        arrays.append(mask)
     return {"layers": len(blocks)}, arrays
 
 

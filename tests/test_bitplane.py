@@ -303,11 +303,11 @@ def test_wire_packed_masks_bitwise():
     pg = _build_graph("arr")
     res = pg.match("(a:p)-[:f]->(b)")
     meta, arrays = wire.result_to_wire(res)
-    assert any(isinstance(a, wire.PackedMask) for a in arrays)
+    assert all(isinstance(a, (wire.PackedMask, wire.SparseMask))
+               for a in arrays)
     frame = wire.encode_msg(dict(meta, op="match_result"), arrays)
-    # PackedMask blobs must be byte-identical to the generic bool path
-    plain = [np.asarray(x) if not isinstance(x, wire.PackedMask)
-             else bitplane.unpack_bits_host(x.words, x.n) for x in arrays]
+    # device-encoded blobs must be byte-identical to the generic bool path
+    plain = [wire._as_bool_mask(x) for x in arrays]
     assert frame == wire.encode_msg(dict(meta, op="match_result"), plain)
     import socket
 

@@ -3,9 +3,10 @@ batching (src/repro/service/{wire,server,client}.py, ARCHITECTURE §9).
 
 The contracts under test:
 
-* the codec round-trips headers and arrays exactly (bool masks travel
-  packbits-packed and come back bitwise-identical), and rejects garbage
-  frames with ``ProtocolError`` instead of misreading them;
+* the codec round-trips headers and arrays exactly (a bool mask travels
+  as its set positions when 32·popcount < n, else packbits-packed, and
+  comes back bitwise-identical either way), and rejects garbage frames
+  with ``ProtocolError`` instead of misreading them;
 * a query through ``PGClient`` → TCP → ``PGServer`` → ``Service`` returns
   masks bitwise-equal to in-process ``PropGraph.match`` (the paper §III
   client–server split must be invisible to correctness), including
@@ -122,6 +123,111 @@ def test_wire_rejects_hostile_array_specs():
             a.close(), b.close()
 
 
+def _mask_with(n, k, seed=0, spread=False):
+    """A (n,) mask with k set bits: random, or one a word (bit 32·i)."""
+    mask = np.zeros(n, bool)
+    if spread:
+        mask[np.arange(k) * 32] = True
+    else:
+        mask[np.random.default_rng(seed).choice(n, k, replace=False)] = True
+    return mask
+
+
+def _masks_encoded():
+    from repro.obs.metrics import GLOBAL
+
+    return {enc: GLOBAL.counter("pg_wire_masks", enc=enc).value()
+            for enc in ("ids", "bitmap")}
+
+
+# (n, popcount, one set bit a word): empty and single bits, the break-even
+# n/32 ± 1 (n a multiple of 32 and not, nor of 8), each edge of the
+# device's nonzero-word buffers (the census's 1024, then 4096, 16384, 65536
+# and the largest ids count; a spread mask fills them exactly), all-true
+@pytest.mark.parametrize("n,k,spread", [
+    (1, 0, False), (1, 1, False), (64, 1, False), (64, 2, False),
+    (100, 3, False), (100, 4, False),
+    (8_191, 0, False), (8_191, 255, False), (8_191, 256, False),
+    (40_000, 1_249, False), (40_000, 1_250, False), (40_000, 1_251, False),
+    (40_003, 1_024, False), (40_003, 1_025, False), (40_003, 1_250, False),
+    (40_003, 1_251, False),
+    (70_000, 1_024, True), (70_000, 1_025, True),
+    (300_007, 4_096, False), (300_007, 4_097, False), (300_007, 4_097, True),
+    (600_007, 16_384, False), (600_007, 16_385, False),
+    (600_007, 16_385, True),
+    (2_100_007, 65_536, False), (2_100_007, 65_537, False),
+    (2_100_007, 65_625, False), (2_100_007, 65_537, True),
+    (1_000, 1_000, False),
+])
+def test_wire_mask_encoding_by_popcount(n, k, spread):
+    """A (n,) bool mask ships as ids exactly when 32·k < n, from a host
+    array and from a device array alike, and comes back bitwise equal."""
+    import jax.numpy as jnp
+
+    mask = _mask_with(n, k, spread=spread)
+    sparse = 32 * k < n
+    (payload,) = wire._mask_payloads([jnp.asarray(mask)])
+    assert isinstance(payload, wire.SparseMask if sparse else wire.PackedMask)
+    assert _eq(wire._as_bool_mask(payload), mask)
+    before = _masks_encoded()
+    frame = wire.encode_msg({"op": "x"}, [payload, mask])
+    after = _masks_encoded()
+    enc = "ids" if sparse else "bitmap"
+    assert after[enc] - before[enc] == 2
+    hdr_len = int.from_bytes(frame[8:12], "big")
+    specs = __import__("json").loads(frame[12:12 + hdr_len])["arrays"]
+    assert [s.get("enc") for s in specs] == ["ids" if sparse else None] * 2
+    a, b = socket.socketpair()
+    try:
+        threading.Thread(target=a.sendall, args=(frame,), daemon=True).start()
+        _, arrays = wire.recv_msg(b)
+    finally:
+        a.close(), b.close()
+    for back in arrays:
+        assert back.dtype == np.bool_ and _eq(back, mask)
+
+
+def _ids_frame(spec, ids):
+    import json
+    import struct
+
+    blob = np.asarray(ids, "<i4").tobytes()
+    hdr = json.dumps({"op": "x", "id": 1, "arrays": [spec]}).encode()
+    payload = struct.pack("!I", len(hdr)) + hdr + blob
+    return wire.MAGIC + struct.pack("!I", len(payload)) + payload
+
+
+def _ids_spec(n, count, **kw):
+    return dict({"dtype": "bool", "shape": [n], "enc": "ids",
+                 "count": count}, **kw)
+
+
+@pytest.mark.parametrize("spec,ids", [
+    (_ids_spec(10, 2), [3, 10]),  # id = n
+    (_ids_spec(10, 2), [-1, 3]),  # negative
+    (_ids_spec(10, 3), [1, 5, 4]),  # unsorted
+    (_ids_spec(10, 3), [1, 4, 4]),  # duplicate
+    (_ids_spec(10, 2), [1, 4, 6]),  # count < blob
+    (_ids_spec(10, 3), [1, 4]),  # count > blob
+    (_ids_spec(3, 4), [0, 1, 2, 2]),  # count > n
+    (_ids_spec(10, -1), []),
+    (_ids_spec(10, "2"), [1, 2]),
+    (_ids_spec(10, True), [1]),
+    (_ids_spec(10, 1, enc="rle"), [1]),
+    (_ids_spec(10, 1, dtype="int32"), [1]),
+    (dict(_ids_spec(10, 1), shape=[2, 5]), [1]),
+    ({"dtype": "bool", "shape": [10], "enc": "ids"}, [1]),  # no count
+])
+def test_wire_rejects_hostile_ids_specs(spec, ids):
+    a, b = socket.socketpair()
+    try:
+        a.sendall(_ids_frame(spec, ids))
+        with pytest.raises(wire.ProtocolError):
+            wire.recv_msg(b)
+    finally:
+        a.close(), b.close()
+
+
 def test_wire_clean_eof_is_connection_error():
     a, b = socket.socketpair()
     a.close()
@@ -167,6 +273,33 @@ def test_net_query_bitwise_equals_match(served):
         assert c.ping()
         for p in PATTERNS:
             _assert_wire_matches(c.query("g", p), pg.match(p))
+
+
+def test_net_reply_masks_as_ids_or_bitmap(served):
+    """A labelled hop's masks come back as ids, an untyped relationship
+    hop's (more than n/32 vertices) as bitmaps, both bitwise equal to the
+    in-process match; a sample reply stays bitwise equal too."""
+    server, pg = served
+    n = pg.graph.n
+    with PGClient(port=server.port) as c:
+        for pattern, enc in (("(a:l1|l2)-[:follows]->(b:l3)", "ids"),
+                             ("(a)-[:follows]->(b)", "bitmap")):
+            ref = pg.match(pattern)
+            dense = 32 * int(np.asarray(ref.vertex_mask).sum()) >= n
+            assert dense == (enc == "bitmap")
+            before = _masks_encoded()
+            _assert_wire_matches(c.query("g", pattern), ref)
+            moved = {e: _masks_encoded()[e] - before[e] for e in before}
+            assert moved[enc] >= 3 and moved[enc] + moved[
+                {"ids": "bitmap", "bitmap": "ids"}[enc]] == 4, moved
+        seeds = np.asarray(pg.graph.node_map)[:32]
+        got = c.sample("g", seeds, [4, 2], seed=8)
+    ref = pg.sample(seeds, [4, 2], seed=8)
+    assert len(got) == len(ref)
+    for bg, br in zip(got, ref):
+        for f in ("src_nodes", "dst_nodes", "edge_src", "edge_dst",
+                  "edge_mask"):
+            assert _eq(getattr(bg, f), getattr(br, f)), f
 
 
 def test_net_pipelined_batch_with_duplicates(served):
